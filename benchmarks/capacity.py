@@ -193,7 +193,7 @@ def run_suite(p: dict) -> dict:
         )
     return {
         "schema": "bench_capacity/v1",
-        "machine": machine_info(),
+        "machine": machine_info(jax.default_backend()),
         "config": p,
         "runs": runs,
         "parity": parity,
@@ -248,6 +248,9 @@ def main():
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--out", default=os.path.normpath(OUT_PATH))
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     p = TINY if args.tiny else FULL
     result = run_suite(dict(p))
     with open(args.out, "w") as f:

@@ -263,6 +263,9 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="exit nonzero if any validation check fails")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     rows = run(
         steps=args.steps,
         num_tables=args.tables,

@@ -247,7 +247,7 @@ def run_suite(p: dict, workdir: str) -> dict:
 
     return {
         "schema": "bench_recovery/v1",
-        "machine": machine_info(),
+        "machine": machine_info(jax.default_backend()),
         "config": p,
         "baseline": baseline,
         "checkpoint": checkpoint,
@@ -278,6 +278,9 @@ def main():
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--out", default=os.path.normpath(OUT_PATH))
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     p = TINY if args.tiny else FULL
     with tempfile.TemporaryDirectory(prefix="bench_recovery_") as workdir:
         result = run_suite(dict(p), workdir)

@@ -240,6 +240,9 @@ def main():
         help="ModelConfig overrides for §Perf variants",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     overrides = {}
     for kv in args.override:
         k, _, v = kv.partition("=")

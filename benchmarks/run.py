@@ -76,13 +76,15 @@ def write_summary(all_ok: bool, total_seconds: float, path: str = SUMMARY_PATH):
     ]
     # same machine-class provenance block as BENCH_wallclock.json and
     # BENCH_serve.json — all three bench artifacts share one schema for it
+    import jax
+
     from benchmarks.wallclock import machine_info
 
     summary = {
         "schema": "bench_summary/v1",
         "all_claims_ok": bool(all_ok),
         "total_bench_seconds": round(total_seconds, 1),
-        "machine": machine_info(),
+        "machine": machine_info(jax.default_backend()),
         "designs": designs,
     }
     os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -204,6 +206,9 @@ def main():
     )
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     if args.tables < 1:
         ap.error("--tables must be >= 1")
     t0 = time.time()

@@ -188,7 +188,7 @@ def run_suite(
 
     return {
         "schema": "bench_serve/v1",
-        "machine": machine_info(),
+        "machine": machine_info(jax.default_backend()),
         "config": {**sz, "cache_frac": CACHE_FRAC, "window": WINDOW,
                    "kernel": kernel, "scenario": scenario},
         "designs": designs,
@@ -242,6 +242,9 @@ def main():
     )
     ap.add_argument("--out", default=os.path.normpath(OUT_PATH))
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     depths = tuple(int(d) for d in args.depths.split(",") if d != "")
     result = run_suite(args.scenario, depths, _sizing(args.tiny),
                        kernel=args.kernel)

@@ -15,13 +15,12 @@ GEMM throughput — dominates: 8 tables x 50k rows, dim 32, small MLPs, batch
 64 x 20 lookups/table (same id-stream shape as the paper config, high-
 locality steady state is high-hit-rate).
 
-The harness feature-detects the fast-path knobs (``executor=``,
-``fused_train_fn=``, planner ``memoize=``) so the identical measurement runs
-against code bases with and without them — that is how the checked-in
-``BENCH_wallclock.json`` carries honest before/after numbers from the same
-container (``--baseline before.json`` merges a previous run in). Every cell
-runs in its OWN subprocess: cells must not share the in-process XLA compile
-cache, or a cell's number would depend on which cells ran before it.
+``--baseline before.json`` merges a previous run in as the "before"
+column. Every measurement runs in its OWN subprocess: cells must not share
+the in-process XLA compile cache, or a cell's number would depend on which
+cells ran before it. The parent process never imports JAX — on an
+accelerator, a parent holding the device would lock its children out — so
+the backend in ``machine`` is the one the children report.
 
 Measured modes: ``sync`` (sync executor, split dispatch — the fast-path
 planner/padding/empty-skip still apply), ``fast`` (overlapped executor +
@@ -49,25 +48,14 @@ from __future__ import annotations
 
 import argparse
 import functools
-import inspect
 import json
 import os
 import subprocess
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import List, Optional
 
-import jax
 import numpy as np
-
-from repro.configs.base import DLRMConfig
-from repro.core.dlrm_runtime import DLRMTrainer
-from repro.core.host_table import HostEmbeddingTable
-from repro.core.plan import Planner
-from repro.core.runtime import make_runtime
-from repro.core.table_group import TableGroup
-from repro.data.lookahead import LookaheadStream
-from repro.data.synthetic import TraceConfig, dlrm_batches, hot_ids_global
 
 # ---- bench config ----------------------------------------------------------
 TABLES = 8
@@ -85,7 +73,9 @@ SCENARIOS = ("synthetic", "drift", "flash_crowd")
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_wallclock.json")
 
 
-def bench_cfg() -> DLRMConfig:
+def bench_cfg():
+    from repro.configs.base import DLRMConfig
+
     return DLRMConfig(
         name="dlrm-wallclock",
         num_tables=TABLES,
@@ -98,42 +88,16 @@ def bench_cfg() -> DLRMConfig:
     )
 
 
-# ---- feature detection (same harness measures pre/post fast-path code) -----
-@functools.lru_cache(maxsize=None)
-def _features() -> Dict[str, bool]:
-    from repro.core.pipeline import ScratchPipe, StepStats
-
-    pipe_params = inspect.signature(ScratchPipe.__init__).parameters
-    plan_params = inspect.signature(Planner.__init__).parameters
-    trainer_params = inspect.signature(DLRMTrainer.__init__).parameters
-    return {
-        "executor": "executor" in pipe_params,
-        "fused": "fused_train_fn" in pipe_params,
-        "memoize": "memoize" in plan_params,
-        "stage_times": "record_stage_times" in pipe_params,
-        "planner": "planner" in pipe_params,
-        "kernel": "kernel" in pipe_params and "kernel" in trainer_params,
-    }
-
-
 def _modes_for(design: str) -> tuple:
     """Measured mode axis per design. ``device`` = overlapped executor +
-    fused dispatch + planner="device" — the all-in fast path; it only runs
-    when the code base has the device planner (feature detection keeps the
-    harness able to measure older checkouts). ``pallas`` = fast +
-    ``kernel="pallas"`` — scratchpipe only (interpret-mode kernels are the
-    dispatch-path smoke, one design covers the axis)."""
+    fused dispatch + planner="device" — the all-in fast path. ``pallas`` =
+    fast + ``kernel="pallas"`` — scratchpipe only (one design covers the
+    kernel axis)."""
     if design == "scratchpipe":
-        modes = ("sync", "fast", "device", "pallas")
-    elif design in ("strawman", "sharded"):
-        modes = ("fast", "device")
-    else:
-        modes = ("fast",)
-    if not _features()["planner"]:
-        modes = tuple(m for m in modes if m != "device")
-    if not _features()["kernel"]:
-        modes = tuple(m for m in modes if m != "pallas")
-    return modes
+        return ("sync", "fast", "device", "pallas")
+    if design in ("strawman", "sharded"):
+        return ("fast", "device")
+    return ("fast",)
 
 
 def _mode_kernel(mode: str) -> str:
@@ -141,9 +105,11 @@ def _mode_kernel(mode: str) -> str:
 
 
 # ---- workloads -------------------------------------------------------------
-def make_batches(scenario: str, group: TableGroup, steps: int) -> list:
+def make_batches(scenario: str, group, steps: int) -> list:
     """Pre-materialized (ids, batch) list — generation cost stays OUT of the
     measured window (we measure the runtime, not the generator)."""
+    from repro.data.synthetic import TraceConfig, dlrm_batches
+
     if scenario == "synthetic":
         tc = TraceConfig(
             num_tables=TABLES,
@@ -176,6 +142,7 @@ def _sharded_train_fn(num_tables: int):
     cannot run through the sharded runtime (bucketing drops bag positions),
     so this cell measures the cache-runtime + dispatch cost around a
     representative embedding update."""
+    import jax
 
     @functools.partial(jax.jit, donate_argnums=0)
     def _add(storage, slots):
@@ -190,32 +157,25 @@ def _sharded_train_fn(num_tables: int):
     return fn
 
 
-def build_runtime(design: str, mode: str, group: TableGroup, host, trainer,
+def build_runtime(design: str, mode: str, group, host, trainer,
                   batches_for_profile) -> object:
-    feats = _features()
+    from repro.core.runtime import make_runtime
+    from repro.data.synthetic import TraceConfig, hot_ids_global
+
     rows = group.total_rows
     slots = max(1024, int(rows * CACHE_FRAC))
+    executor = "sync" if mode == "sync" else "overlapped"
+    planner = "device" if mode == "device" else "host"
     if design in ("scratchpipe", "strawman"):
-        kw = {"num_slots": slots}
-        if feats["executor"]:
-            kw["executor"] = "sync" if mode == "sync" else "overlapped"
-        if feats["fused"] and mode in ("fast", "device", "pallas"):
+        kw = {"num_slots": slots, "executor": executor, "planner": planner,
+              "record_stage_times": True,
+              "kernel": _mode_kernel(mode)}  # runtime-side [Insert] fills
+        if mode in ("fast", "device", "pallas"):
             kw["fused_train_fn"] = trainer.fused_train_fn
-        if feats["stage_times"]:
-            kw["record_stage_times"] = True
-        if feats["planner"] and mode == "device":
-            kw["planner"] = "device"
-        if feats["kernel"]:
-            kw["kernel"] = _mode_kernel(mode)  # runtime-side [Insert] fills
         return make_runtime(design, host, trainer.train_fn, **kw)
     if design == "sharded":
-        kw = {"num_slots": slots, "table_group": group}
-        if feats["executor"]:
-            kw["executor"] = "sync" if mode == "sync" else "overlapped"
-        if feats["stage_times"]:
-            kw["record_stage_times"] = True
-        if feats["planner"] and mode == "device":
-            kw["planner"] = "device"
+        kw = {"num_slots": slots, "table_group": group, "executor": executor,
+              "record_stage_times": True, "planner": planner}
         return make_runtime(
             design, host, _sharded_train_fn(group.num_tables), **kw
         )
@@ -251,14 +211,20 @@ def _sync(runtime, trainer):
 # ---- one measured cell -----------------------------------------------------
 def measure_cell(design: str, scenario: str, mode: str, warmup: int,
                  steps: int) -> dict:
+    import jax
+
+    from repro.core.dlrm_runtime import DLRMTrainer
+    from repro.core.host_table import HostEmbeddingTable
+    from repro.core.table_group import TableGroup
+    from repro.data.lookahead import LookaheadStream
+
     cfg = bench_cfg()
     group = TableGroup.from_config(cfg)
     items = make_batches(scenario, group, warmup + steps)
     profile = items[: max(1, warmup // 2)] if scenario != "synthetic" else None
     host = HostEmbeddingTable(group.total_rows, cfg.embed_dim, seed=1)
     kernel = _mode_kernel(mode)
-    tkw = {"kernel": kernel} if _features()["kernel"] else {}
-    trainer = DLRMTrainer(cfg, jax.random.key(0), lr=0.05, **tkw)
+    trainer = DLRMTrainer(cfg, jax.random.key(0), lr=0.05, kernel=kernel)
     runtime = build_runtime(design, mode, group, host, trainer, profile)
 
     stream = LookaheadStream(iter(items))
@@ -310,7 +276,7 @@ def measure_cell(design: str, scenario: str, mode: str, warmup: int,
         "scenario": scenario,
         "mode": mode,
         "kernel": kernel,
-        "features": _features(),
+        "backend": jax.default_backend(),
         "steps": n_trained,
         "steps_per_s": round(n_trained / elapsed, 3) if elapsed > 0 else 0.0,
         "ms_per_step": round(elapsed / max(n_trained, 1) * 1e3, 4),
@@ -321,18 +287,17 @@ def measure_cell(design: str, scenario: str, mode: str, warmup: int,
 
 # ---- planner microbench ----------------------------------------------------
 def measure_planner(scenario: str, steps: int, memoize: bool) -> dict:
+    from repro.core.plan import Planner
+    from repro.core.table_group import TableGroup
+
     cfg = bench_cfg()
     group = TableGroup.from_config(cfg)
     items = make_batches(scenario, group, steps + 2)
     ids_list = [np.asarray(ids) for ids, _ in items]
     rows = group.total_rows
     slots = max(1024, int(rows * CACHE_FRAC))
-    kw = {}
-    memo_effective = False
-    if _features()["memoize"]:
-        kw["memoize"] = memoize
-        memo_effective = memoize
-    planner = Planner(rows, slots, past_window=3, future_window=2, **kw)
+    planner = Planner(rows, slots, past_window=3, future_window=2,
+                      memoize=memoize)
     t0 = time.perf_counter()
     for i in range(steps):
         planner.plan(ids_list[i], [ids_list[i + 1], ids_list[i + 2]])
@@ -340,7 +305,7 @@ def measure_planner(scenario: str, steps: int, memoize: bool) -> dict:
     return {
         "scenario": scenario,
         "placement": "host",
-        "memoize": memo_effective,
+        "memoize": memoize,
         "steps": steps,
         "us_per_batch": round(elapsed / steps * 1e6, 1),
     }
@@ -357,6 +322,7 @@ def measure_planner_device(scenario: str, steps: int, scan: bool) -> dict:
     import jax.numpy as jnp
 
     from repro.core.plan_jax import DevicePlanner, init_state, plan_window
+    from repro.core.table_group import TableGroup
 
     cfg = bench_cfg()
     group = TableGroup.from_config(cfg)
@@ -413,13 +379,12 @@ def measure_launches() -> List[dict]:
     "<= 2 pallas_call launches per cycle per pad bucket" claim: the whole
     embedding fwd+bwd collapses into 1 fused fill+gather call and 1
     coalesce+scatter call."""
+    import jax
     import jax.numpy as jnp
 
-    from repro.core.dlrm_runtime import dlrm_fill_train_step
+    from repro.core.dlrm_runtime import DLRMTrainer, dlrm_fill_train_step
     from repro.launch.hlo_stats import jaxpr_primitive_counts
 
-    if not _features()["kernel"]:
-        return []
     cfg = bench_cfg()
     n_slots = max(1024, int(TABLES * ROWS_PER_TABLE * CACHE_FRAC))
     F = 256  # one pad bucket's worth of fills
@@ -450,39 +415,63 @@ def measure_launches() -> List[dict]:
     return out
 
 
-def machine_info() -> dict:
+def machine_info(backend: Optional[str] = None) -> dict:
     """Provenance for checked-in numbers: the gate compares across machines,
-    so every recorded run says what class of machine produced it."""
+    so every recorded run says what class of machine produced it. The
+    parent never touches JAX, so ``backend`` is what the children reported
+    (None when no child has run)."""
     import platform
+    from importlib import metadata
 
     return {
         "platform": platform.platform(),
         "machine": platform.machine(),
         "cpus": os.cpu_count(),
         "python": platform.python_version(),
-        "jax": jax.__version__,
-        "backend": jax.default_backend(),
+        "jax": metadata.version("jax"),
+        "backend": backend,
     }
 
 
+def measure_aux(scenarios, planner_steps: int) -> dict:
+    """The [Plan] microbenches and the launch counts (one child process)."""
+    import jax
+
+    planner = []
+    for scenario in scenarios:
+        for memoize in (False, True):
+            planner.append(measure_planner(scenario, planner_steps, memoize))
+        for scan in (False, True):
+            planner.append(
+                measure_planner_device(scenario, planner_steps, scan)
+            )
+    return {"planner": planner, "launches": measure_launches(),
+            "backend": jax.default_backend()}
+
+
 # ---- driver ----------------------------------------------------------------
-def _measure_cell_isolated(design: str, scenario: str, mode: str,
-                           warmup: int, steps: int) -> dict:
-    """Run one cell in a fresh process. Cells share nothing — in
-    particular not the in-process XLA compile cache, which would otherwise
-    make a cell's number depend on which cells ran before it."""
-    cmd = [
-        sys.executable, "-m", "benchmarks.wallclock",
-        "--cell", design, scenario, mode,
-        "--warmup", str(warmup), "--steps", str(steps),
-    ]
+def _run_isolated(child_args: List[str], what: str) -> dict:
+    """Run one measurement in a fresh process and return its CELL_RESULT.
+    Cells share nothing — in particular not the in-process XLA compile
+    cache, which would otherwise make a cell's number depend on which cells
+    ran before it."""
+    cmd = [sys.executable, "-m", "benchmarks.wallclock"] + child_args
     out = subprocess.run(cmd, capture_output=True, text=True)
     for line in out.stdout.splitlines():
         if line.startswith("CELL_RESULT "):
             return json.loads(line[len("CELL_RESULT "):])
     raise RuntimeError(
-        f"cell {design}/{scenario}/{mode} produced no result:\n"
+        f"{what} produced no result:\n"
         f"{out.stdout[-2000:]}\n{out.stderr[-2000:]}"
+    )
+
+
+def _measure_cell_isolated(design: str, scenario: str, mode: str,
+                           warmup: int, steps: int) -> dict:
+    return _run_isolated(
+        ["--cell", design, scenario, mode,
+         "--warmup", str(warmup), "--steps", str(steps)],
+        f"cell {design}/{scenario}/{mode}",
     )
 
 
@@ -500,27 +489,24 @@ def run_suite(warmup: int, steps: int, planner_steps: int) -> dict:
                     f"hit={cell['hit_rate']:.3f}",
                     flush=True,
                 )
-    planner = []
-    for scenario in SCENARIOS:
-        for memoize in (False, True):
-            cell = measure_planner(scenario, planner_steps, memoize)
-            planner.append(cell)
+    aux = _run_isolated(
+        ["--aux", "--planner-steps", str(planner_steps)], "planner/launches"
+    )
+    planner, launches = aux["planner"], aux["launches"]
+    for cell in planner:
+        if cell["placement"] == "host":
             print(
-                f"planner      {scenario:<12} host  memoize="
+                f"planner      {cell['scenario']:<12} host  memoize="
                 f"{str(cell['memoize']):<5} "
                 f"{cell['us_per_batch']:>8.1f} us/batch",
                 flush=True,
             )
-        if _features()["planner"]:
-            for scan in (False, True):
-                cell = measure_planner_device(scenario, planner_steps, scan)
-                planner.append(cell)
-                print(
-                    f"planner      {scenario:<12} device {cell['mode']:<5} "
-                    f"{cell['us_per_batch']:>8.1f} us/batch",
-                    flush=True,
-                )
-    launches = measure_launches()
+        else:
+            print(
+                f"planner      {cell['scenario']:<12} device {cell['mode']:<5} "
+                f"{cell['us_per_batch']:>8.1f} us/batch",
+                flush=True,
+            )
     for rec in launches:
         print(
             f"launches     kernel={rec['kernel']:<7} "
@@ -531,7 +517,7 @@ def run_suite(warmup: int, steps: int, planner_steps: int) -> dict:
         )
     return {
         "schema": "bench_wallclock/v1",
-        "machine": machine_info(),
+        "machine": machine_info(aux["backend"]),
         "config": {
             "tables": TABLES,
             "rows_per_table": ROWS_PER_TABLE,
@@ -543,7 +529,6 @@ def run_suite(warmup: int, steps: int, planner_steps: int) -> dict:
             "warmup": warmup,
             "steps": steps,
         },
-        "features": _features(),
         "runs": runs,
         "planner": planner,
         "launches": launches,
@@ -765,18 +750,17 @@ def check(result: dict) -> List[str]:
                 )
     if not result["planner"]:
         problems.append("planner section empty")
-    if _features()["kernel"]:
-        kernels = {c.get("kernel", "xla") for c in result["runs"]}
-        if "pallas" not in kernels:
-            problems.append("no kernel=pallas cell in runs (dispatch rot)")
-        for rec in result.get("launches", []):
-            if rec["kernel"] == "pallas" and rec["pallas_calls_per_cycle"] > 2:
-                problems.append(
-                    f"pallas cycle dispatches {rec['pallas_calls_per_cycle']} "
-                    "pallas_call launches (> 2 per pad bucket)"
-                )
-        if not result.get("launches"):
-            problems.append("launches section empty")
+    kernels = {c.get("kernel", "xla") for c in result["runs"]}
+    if "pallas" not in kernels:
+        problems.append("no kernel=pallas cell in runs (dispatch rot)")
+    for rec in result.get("launches", []):
+        if rec["kernel"] == "pallas" and rec["pallas_calls_per_cycle"] > 2:
+            problems.append(
+                f"pallas cycle dispatches {rec['pallas_calls_per_cycle']} "
+                "pallas_call launches (> 2 per pad bucket)"
+            )
+    if not result.get("launches"):
+        problems.append("launches section empty")
     return problems
 
 
@@ -789,6 +773,12 @@ def main():
         metavar=("DESIGN", "SCENARIO", "MODE"),
         default=None,
         help="internal: measure one cell and print CELL_RESULT json",
+    )
+    ap.add_argument(
+        "--aux",
+        action="store_true",
+        help="internal: measure the planner microbenches and launch counts "
+        "and print CELL_RESULT json",
     )
     ap.add_argument("--warmup", type=int, default=None)
     ap.add_argument("--steps", type=int, default=None)
@@ -847,9 +837,14 @@ def main():
     planner_steps = args.planner_steps if args.planner_steps is not None else (
         GATE_PLANNER_STEPS if args.tiny else 200
     )
-    if args.cell is not None:
-        design, scenario, mode = args.cell
-        cell = measure_cell(design, scenario, mode, warmup, steps)
+    if args.cell is not None or args.aux:
+        from repro.launch.compile_cache import setup_compile_cache
+
+        setup_compile_cache()
+        if args.aux:
+            cell = measure_aux(SCENARIOS, planner_steps)
+        else:
+            cell = measure_cell(*args.cell, warmup, steps)
         print("CELL_RESULT " + json.dumps(cell))
         return
     result = run_suite(warmup, steps, planner_steps)
@@ -885,7 +880,9 @@ def main():
         if args.gate_fallback and os.path.exists(args.gate_fallback):
             with open(args.gate_fallback) as f:
                 fallback = json.load(f)
-        baseline, skip, notes = resolve_gate_baseline(gate_baseline, fallback)
+        baseline, skip, notes = resolve_gate_baseline(
+            gate_baseline, fallback, current=result["machine"]
+        )
         for n in notes:
             print(f"  [GATE] {n}")
         if baseline is None:

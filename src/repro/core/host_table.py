@@ -23,6 +23,9 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+#: values per float64 draw when a table initializes itself (128 MiB)
+_INIT_CHUNK_VALUES = 1 << 24
+
 
 class RowCorruptionError(RuntimeError):
     """One or more host-table rows no longer match their checksums."""
@@ -74,9 +77,15 @@ class HostEmbeddingTable:
             assert data.shape == (rows, dim)
             self.data = data
         else:
+            # drawn in row chunks: the same values as one (rows, dim) draw,
+            # without a float64 temporary twice the table's size
             rng = np.random.default_rng(seed)
             scale = 1.0 / np.sqrt(dim)
-            self.data = (rng.standard_normal((rows, dim)) * scale).astype(dtype)
+            self.data = np.empty((rows, dim), dtype)
+            chunk = max(1, _INIT_CHUNK_VALUES // max(dim, 1))
+            for lo in range(0, rows, chunk):
+                hi = min(lo + chunk, rows)
+                self.data[lo:hi] = rng.standard_normal((hi - lo, dim)) * scale
         self.traffic = HostTraffic()
         self._sums: Optional[np.ndarray] = None
         if guard:

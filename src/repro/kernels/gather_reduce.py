@@ -1,33 +1,36 @@
 """Pallas TPU kernels: the [Insert]/[Train] forward primitives (paper §II-B).
 
-Three kernels share one design language — scalar-prefetched int32 operand
-streams in SMEM drive the *index maps* of the storage BlockSpec, so each
-grid step DMAs exactly one (1, d_tile) embedding-row tile HBM<->VMEM:
+Storage never enters the Pallas block pipeline. It stays in HBM
+(``memory_space=pl.ANY``) and every row access is an explicit DMA that the
+kernel starts and waits for itself, so the order of row reads and writes is
+the program order below and nothing else:
 
-  * ``gather_reduce``  — embedding gather + bag reduction (the seed kernel).
-    grid (n_bags, L, D//d_tile); bags revisit their output block across the
-    L lookup steps, so the fp32 accumulator never leaves VMEM and the
-    reduction is sequential-in-l by construction (the property the XLA path
-    mirrors for bit-parity, see kernels/ref.py).
-  * ``fill``           — [Insert]-stage drop-mode scatter of fetched rows.
-    Slots are bucket-padded with out-of-bounds sentinels; a prefetched
-    valid mask predicates the write (``pl.when``), the block index is
-    clamped in-range so the DMA is always legal, and an unmodified block
-    writes back its own fetched values (a value-level no-op).
-  * ``fill_gather_reduce`` — the FUSED forward: one pallas_call covering the
-    [Insert]-fill AND the translated-slot gather/reduce of a pipeline
-    cycle. The op stream is ``F fill ops ++ nb*L gather ops`` on the inner
-    grid axis; because the TPU grid executes sequentially, every gather of
-    a just-filled row reads the filled value (intra-kernel RAW through the
-    aliased storage output), and the fill→gather order equals the split
-    engine's intra-cycle order — so the fused kernel is bit-identical to
-    fill-then-gather. Storage is input/output-aliased (in-place fill);
-    bags are a second fp32 output.
+  * a read DMAs the row into VMEM and waits before the row is used;
+  * a write DMAs the row out and waits before the next access starts, so a
+    later access to the same row sees it;
+  * no kernel reads an output block it has not written in the same step.
+
+Row copies follow the HBM tiling. A 32-bit row is its own DMA slice. A
+packed row (bf16, int8) can only move as the aligned ``PACKED_ROWS``-row
+block that holds it: reads pick the row out of the block, writes put it
+into the block and copy the whole block back (``row_block``).
+
+  * ``gather_reduce`` — embedding gather + bag reduction. Each grid step
+    reduces ``G`` bags: a bag's L row copies are started together, then
+    summed in lookup order (sequential-in-l, the order kernels/ref.py pins).
+    An int8 payload dequantizes in-kernel against its per-row fp32 scale.
+  * ``fill`` — [Insert]-stage drop-mode scatter of fetched rows. Slots are
+    bucket-padded with out-of-bounds sentinels (>= N); those ops do nothing.
+  * ``fill_gather_reduce`` — the FUSED forward: one pallas_call whose grid
+    runs the fill steps first and the gather steps after, so every gather
+    of a just-filled row reads the filled value — bit-identical to
+    fill-then-gather. Storage is input/output-aliased (in-place fill); bags
+    are a second fp32 output.
 
 Grid sizes come from the pipeline's pow-2/adaptive pad buckets (plan.py):
-static shapes => one cached executable per bucket, the PinnedCache
-discipline. Wrapper-level lane padding and empty-operand guards live in
-kernels/ops.py; these kernels keep the hard ``D % d_tile == 0`` contract.
+static shapes => one cached executable per bucket. Row-count padding for
+packed storages and the empty-operand guards live in kernels/ops.py; these
+kernels assert ``N % row_block(dtype) == 0``.
 """
 from __future__ import annotations
 
@@ -35,323 +38,283 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_D_TILE = 128
+#: bags (gather steps) or fill rows (fill steps) per grid step: the fp32
+#: sublane tile, so the (G, D) bag and fill-row blocks meet the block rule
+G = 8
+#: rows per DMA for packed storage dtypes: the HBM tile height
+PACKED_ROWS = 8
+#: lanes per row of the 2-D view of the int8 scale column (see _scale_rows)
+SCALE_LANES = 128
+
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
-def _gather_kernel(ids_ref, storage_ref, out_ref):
-    l = pl.program_id(1)
-
-    @pl.when(l == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += storage_ref[...].astype(out_ref.dtype)
+def row_block(dtype) -> int:
+    """Rows moved per storage DMA: 1 for 32-bit rows, the aligned tile
+    height for packed (16- and 8-bit) rows."""
+    return 1 if jnp.dtype(dtype).itemsize == 4 else PACKED_ROWS
 
 
-@functools.partial(jax.jit, static_argnames=("d_tile", "interpret"))
-def gather_reduce(
-    storage: jax.Array,
-    slot_ids: jax.Array,
-    *,
-    d_tile: int = DEFAULT_D_TILE,
-    interpret: bool = False,
-) -> jax.Array:
-    """storage (N, D); slot_ids (nb, L) int32 -> (nb, D) fp32 bags."""
-    nb, L = slot_ids.shape
-    N, D = storage.shape
-    d_tile = min(d_tile, D)
-    assert D % d_tile == 0, (D, d_tile)
-    flat_ids = slot_ids.reshape(-1).astype(jnp.int32)
-    out = pl.pallas_call(
-        _gather_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nb, L, D // d_tile),
-            in_specs=[
-                pl.BlockSpec((1, d_tile), lambda b, l, d, ids: (ids[b * L + l], d)),
-            ],
-            out_specs=pl.BlockSpec((1, d_tile), lambda b, l, d, ids: (b, d)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((nb, D), jnp.float32),
-        interpret=interpret,
-    )(flat_ids, storage)
-    return out
+def _rows_at(hbm, slot, R):
+    """The HBM window a DMA of row ``slot`` moves (R rows, R-aligned)."""
+    if R == 1:
+        return hbm.at[pl.ds(slot, 1)]
+    return hbm.at[pl.ds(pl.multiple_of((slot // R) * R, R), R)]
 
 
-def _gather_q_kernel(ids_ref, storage_ref, scale_ref, out_ref):
-    # Dequantize IN-KERNEL: each addend is ``row_tile.astype(f32) * scale``
-    # (the per-row scale rides a (1, 1) block keyed by the same prefetched
-    # slot stream), then the same sequential-in-l accumulation as the fp32
-    # gather. The compiler may contract the mul+accumulate into an FMA —
-    # harmless, because the product is EXACT in fp32 by the scale-snap
-    # discipline (core/quantize.py): int8 payload has 7 significant bits,
-    # the snapped scale <= 17, so the FMA rounds identically to
-    # mul-then-add and parity with kernels/ref.py holds on any backend.
-    l = pl.program_id(1)
-
-    @pl.when(l == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += storage_ref[...].astype(out_ref.dtype) * scale_ref[0, 0]
+def pick_row(block, slot, R):
+    """Row ``slot`` of a fetched (R, D) block, as fp32 (1, D). The other rows
+    are masked to -0.0, the exact additive identity, so the sum is the row
+    bit for bit."""
+    x = block.astype(jnp.float32)
+    if R == 1:
+        return x
+    sel = lax.broadcasted_iota(jnp.int32, x.shape, 0) == slot % R
+    return jnp.sum(jnp.where(sel, x, -0.0), axis=0, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("d_tile", "interpret"))
-def gather_reduce_q(
-    storage: jax.Array,
-    scale: jax.Array,
-    slot_ids: jax.Array,
-    *,
-    d_tile: int = DEFAULT_D_TILE,
-    interpret: bool = False,
-) -> jax.Array:
-    """int8 storage (N, D) + per-row fp32 scale (N, 1); slot_ids (nb, L)
-    int32 -> (nb, D) fp32 bags, dequantized in-kernel."""
-    nb, L = slot_ids.shape
-    N, D = storage.shape
-    d_tile = min(d_tile, D)
-    assert D % d_tile == 0, (D, d_tile)
-    flat_ids = slot_ids.reshape(-1).astype(jnp.int32)
-    return pl.pallas_call(
-        _gather_q_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nb, L, D // d_tile),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, d_tile), lambda b, l, d, ids: (ids[b * L + l], d)
-                ),
-                pl.BlockSpec((1, 1), lambda b, l, d, ids: (ids[b * L + l], 0)),
-            ],
-            out_specs=pl.BlockSpec((1, d_tile), lambda b, l, d, ids: (b, d)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((nb, D), jnp.float32),
-        interpret=interpret,
-    )(flat_ids, storage, scale)
+def put_row(block, slot, new, R):
+    """``block`` with row ``slot`` replaced by the same row of ``new``
+    (``new`` broadcasts: a (1, D) row or a full (R, D) block)."""
+    if R == 1:
+        return jnp.broadcast_to(new, block.shape).astype(block.dtype)
+    sel = lax.broadcasted_iota(jnp.int32, block.shape, 0) == slot % R
+    return jnp.where(sel, new, block)
 
 
-def _fill_kernel(slot_ref, valid_ref, rows_ref, st_in_ref, st_out_ref):
-    del slot_ref, st_in_ref
-    i = pl.program_id(0)
-
-    @pl.when(valid_ref[i] == 1)
-    def _write():
-        st_out_ref[...] = rows_ref[...].astype(st_out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("d_tile", "interpret"))
-def fill(
-    storage: jax.Array,
-    fill_slots: jax.Array,
-    rows: jax.Array,
-    *,
-    d_tile: int = DEFAULT_D_TILE,
-    interpret: bool = False,
-) -> jax.Array:
-    """storage (N, D); fill_slots (F,) int32, sentinel-padded with values
-    >= N (dropped); rows (F, D). Returns the filled storage."""
-    (F,) = fill_slots.shape
-    N, D = storage.shape
-    d_tile = min(d_tile, D)
-    assert D % d_tile == 0, (D, d_tile)
-    slots = fill_slots.astype(jnp.int32)
-    valid = (slots < N).astype(jnp.int32)
-    slots = jnp.clip(slots, 0, N - 1)  # block index must stay DMA-legal
-    return pl.pallas_call(
-        _fill_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(F, D // d_tile),
-            in_specs=[
-                pl.BlockSpec((1, d_tile), lambda i, d, s, v: (i, d)),  # rows
-                pl.BlockSpec(
-                    (1, d_tile), lambda i, d, s, v: (s[i], d)
-                ),  # storage (aliased with the output)
-            ],
-            out_specs=pl.BlockSpec((1, d_tile), lambda i, d, s, v: (s[i], d)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((N, D), storage.dtype),
-        input_output_aliases={3: 0},  # (slots=0, valid=1, rows=2, storage=3)
-        interpret=interpret,
-    )(slots, valid, rows, storage)
+def _scale_rows(scale):
+    """(N, 1) fp32 scale column -> (ceil(N/128), 128): one DMA-able lane
+    row per 128 slots (a single-element slice of the column is not)."""
+    flat = scale.reshape(-1)
+    pad = (-flat.shape[0]) % SCALE_LANES
+    return jnp.pad(flat, (0, pad)).reshape(-1, SCALE_LANES)
 
 
-def _make_fused_kernel(F: int, L: int):
-    def _kernel(op_slot_ref, op_valid_ref, rows_ref, st_in_ref, st_out_ref,
-                bags_ref):
-        # The storage output aliases the storage input and the sequential
-        # TPU grid re-fetches the output block per step, so the gather ops
-        # (i >= F) observe every fill op's write — the intra-kernel
-        # [Insert]->[Train] RAW the fused dispatch depends on.
-        del op_slot_ref, st_in_ref
-        i = pl.program_id(1)
+def _gather_bags(step, ids_ref, st_hbm, sc_hbm, out_ref, buf, sbuf, sem, *,
+                 nb, L, R):
+    """Reduce this step's G bags (ids_ref[g, l]) into out_ref (G, D)."""
 
-        @pl.when((i < F) & (op_valid_ref[i] == 1))
+    def bag(g, carry):
+        @pl.when(step * G + g < nb)  # padded bags do no work
+        def _bag():
+            def copies(l):
+                s = ids_ref[g, l]
+                cps = [pltpu.make_async_copy(
+                    _rows_at(st_hbm, s, R), buf.at[l], sem.at[0, l])]
+                if sc_hbm is not None:
+                    cps.append(pltpu.make_async_copy(
+                        sc_hbm.at[pl.ds(s // SCALE_LANES, 1)],
+                        sbuf.at[pl.ds(l, 1)], sem.at[1, l]))
+                return cps
+
+            def start(l, carry):
+                for cp in copies(l):
+                    cp.start()
+                return carry
+
+            def addend(l):
+                for cp in copies(l):
+                    cp.wait()
+                s = ids_ref[g, l]
+                x = pick_row(buf[l], s, R)
+                if sc_hbm is not None:
+                    # exact product (snapped scales, core/quantize.py), so an
+                    # FMA contraction rounds like mul-then-add
+                    x = x * sbuf[l, s % SCALE_LANES]
+                return x
+
+            lax.fori_loop(0, L, start, 0)
+            acc = lax.fori_loop(1, L, lambda l, a: a + addend(l), addend(0))
+            out_ref[pl.ds(g, 1), :] = acc
+
+        return carry
+
+    lax.fori_loop(0, G, bag, 0)
+
+
+def _fill_rows(step, slots_ref, rows_ref, st_hbm, buf, sem, *, N, R):
+    """Write this step's G fill rows into their slots (sentinels >= N skip)."""
+
+    def fill_one(g, carry):
+        s = slots_ref[g, 0]
+
+        @pl.when((s >= 0) & (s < N))
         def _fill():
-            st_out_ref[...] = rows_ref[...].astype(st_out_ref.dtype)
+            dst = _rows_at(st_hbm, s, R)
+            if R == 1:
+                src = rows_ref.at[pl.ds(g, 1)]
+            else:  # read-modify-write of the packed block holding the row
+                rd = pltpu.make_async_copy(dst, buf, sem)
+                rd.start()
+                rd.wait()
+                row = pick_row(rows_ref[...], g, G).astype(buf.dtype)
+                buf[...] = put_row(buf[...], s, row, R)
+                src = buf
+            wr = pltpu.make_async_copy(src, dst, sem)
+            wr.start()
+            wr.wait()
 
-        @pl.when(i >= F)
-        def _gather():
-            l = (i - F) % L
+        return carry
 
-            @pl.when(l == 0)
-            def _init():
-                bags_ref[...] = jnp.zeros_like(bags_ref)
-
-            bags_ref[...] += st_out_ref[...].astype(bags_ref.dtype)
-
-    return _kernel
+    lax.fori_loop(0, G, fill_one, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("d_tile", "interpret"))
-def fill_gather_reduce(
-    storage: jax.Array,
-    fill_slots: jax.Array,
-    fill_rows: jax.Array,
-    slot_ids: jax.Array,
-    *,
-    d_tile: int = DEFAULT_D_TILE,
-    interpret: bool = False,
-):
+def _gather_scratch(L, R, D, dtype, quant):
+    return [
+        pltpu.VMEM((L, R, D), dtype),
+        pltpu.SMEM((L, SCALE_LANES), jnp.float32) if quant else None,
+        pltpu.SemaphoreType.DMA((2, L)),
+    ]
+
+
+def _pad_bags(slot_ids):
+    nb = slot_ids.shape[0]
+    return jnp.pad(slot_ids.astype(jnp.int32), ((0, (-nb) % G), (0, 0)))
+
+
+def _pad_fills(fill_slots, rows, N):
+    pad = (-fill_slots.shape[0]) % G
+    slots = jnp.pad(fill_slots.astype(jnp.int32), (0, pad), constant_values=N)
+    return slots[:, None], jnp.pad(rows, ((0, pad), (0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gather_reduce(storage, slot_ids, scale=None, *, interpret=False):
+    """storage (N, D); slot_ids (nb, L) int32 -> (nb, D) fp32 bags. With an
+    (N, 1) fp32 ``scale`` (int8 storage) each row dequantizes in-kernel."""
+    nb, L = slot_ids.shape
+    N, D = storage.shape
+    R = row_block(storage.dtype)
+    assert N % R == 0, (N, R)  # row padding lives in ops.py
+    quant = scale is not None
+    ids = _pad_bags(slot_ids)
+
+    def kernel(ids_ref, st_hbm, *refs):
+        if quant:
+            sc_hbm, out_ref, buf, sbuf, sem = refs
+        else:
+            (out_ref, buf, sem), sc_hbm, sbuf = refs, None, None
+        _gather_bags(pl.program_id(0), ids_ref, st_hbm, sc_hbm, out_ref, buf,
+                     sbuf, sem, nb=nb, L=L, R=R)
+
+    operands = [ids, storage] + ([_scale_rows(scale)] if quant else [])
+    out = pl.pallas_call(
+        kernel,
+        grid=(ids.shape[0] // G,),
+        in_specs=[
+            pl.BlockSpec((G, L), lambda i: (i, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ] + ([pl.BlockSpec(memory_space=pl.ANY)] if quant else []),
+        out_specs=pl.BlockSpec((G, D), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(ids.shape[:1] + (D,), jnp.float32),
+        scratch_shapes=[s for s in _gather_scratch(L, R, D, storage.dtype, quant)
+                        if s is not None],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+    )(*operands)
+    return out[:nb]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fill(storage, fill_slots, rows, *, interpret=False):
+    """storage (N, D); fill_slots (F,) int32, sentinel-padded with values
+    >= N (dropped); rows (F, D). Returns the filled storage (in place)."""
+    N, D = storage.shape
+    R = row_block(storage.dtype)
+    assert N % R == 0, (N, R)
+    slots, rows = _pad_fills(fill_slots, rows.astype(storage.dtype), N)
+
+    def kernel(slots_ref, rows_ref, st_in, st_hbm, buf, sem):
+        del st_in  # aliased with st_hbm
+        _fill_rows(pl.program_id(0), slots_ref, rows_ref, st_hbm, buf, sem,
+                   N=N, R=R)
+
+    return pl.pallas_call(
+        kernel,
+        grid=(slots.shape[0] // G,),
+        in_specs=[
+            pl.BlockSpec((G, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((G, D), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((N, D), storage.dtype),
+        scratch_shapes=[pltpu.VMEM((R, D), storage.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={2: 0},  # (slots=0, rows=1, storage=2)
+        compiler_params=_PARAMS,
+        interpret=interpret,
+    )(slots, rows, storage)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def fill_gather_reduce(storage, fill_slots, fill_rows, slot_ids, scale=None,
+                       *, interpret=False):
     """Fused [Insert]-fill + gather/bag-reduce: storage (N, D); fill_slots
-    (F,) sentinel-padded; fill_rows (F, D); slot_ids (nb, L) int32.
+    (F,) sentinel-padded; fill_rows (F, D); slot_ids (nb, L) int32; for int8
+    storage the (N, 1) ``scale`` must ALREADY hold the fill rows' scales.
     Returns (filled storage (N, D), fp32 bags (nb, D)) from ONE pallas_call.
 
-    Grid (D//d_tile, F + nb*L): the lane axis is OUTER so each d-slice
-    replays the full fill->gather op stream; within a slice the bag block
-    (b, d) is touched only by bag b's L contiguous gather steps, so the
-    VMEM accumulator init-at-l==0 discipline carries over from the plain
-    gather kernel unchanged."""
+    Grid: ceil(F/G) fill steps, then ceil(nb/G) gather steps. Each block
+    spec parks on its last (or first) block during the other phase."""
     nb, L = slot_ids.shape
-    (F,) = fill_slots.shape
     N, D = storage.shape
-    d_tile = min(d_tile, D)
-    assert D % d_tile == 0, (D, d_tile)
-    assert F > 0 and nb * L > 0, (F, nb, L)  # empty guards live in ops.py
-    fslots = fill_slots.astype(jnp.int32)
-    valid = (fslots < N).astype(jnp.int32)
-    fslots = jnp.clip(fslots, 0, N - 1)
-    op_slot = jnp.concatenate([fslots, slot_ids.reshape(-1).astype(jnp.int32)])
-    op_valid = jnp.concatenate([valid, jnp.ones((nb * L,), jnp.int32)])
+    R = row_block(storage.dtype)
+    assert N % R == 0, (N, R)
+    assert fill_slots.shape[0] > 0 and nb * L > 0  # empty guards: ops.py
+    quant = scale is not None
+    slots, rows = _pad_fills(fill_slots, fill_rows.astype(storage.dtype), N)
+    ids = _pad_bags(slot_ids)
+    nf = slots.shape[0] // G
+
+    def kernel(slots_ref, rows_ref, ids_ref, st_in, *refs):
+        del st_in  # aliased with st_hbm
+        if quant:
+            sc_hbm, st_hbm, out_ref, buf, rbuf, sbuf, sem = refs
+        else:
+            (st_hbm, out_ref, buf, rbuf, sem), sc_hbm, sbuf = refs, None, None
+        i = pl.program_id(0)
+
+        @pl.when(i < nf)
+        def _fills():
+            _fill_rows(i, slots_ref, rows_ref, st_hbm, rbuf, sem.at[0, 0],
+                       N=N, R=R)
+
+        @pl.when(i >= nf)
+        def _gathers():
+            _gather_bags(i - nf, ids_ref, st_hbm, sc_hbm, out_ref, buf, sbuf,
+                         sem, nb=nb, L=L, R=R)
+
+    fill_step = lambda i: (jnp.minimum(i, nf - 1), 0)  # noqa: E731
+    bag_step = lambda i: (jnp.maximum(i - nf, 0), 0)  # noqa: E731
+    scratch = _gather_scratch(L, R, D, storage.dtype, quant)
+    scratch.insert(1, pltpu.VMEM((R, D), storage.dtype))
+    operands = [slots, rows, ids, storage] + (
+        [_scale_rows(scale)] if quant else [])
     storage_out, bags = pl.pallas_call(
-        _make_fused_kernel(F, L),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(D // d_tile, F + nb * L),
-            in_specs=[
-                # fill rows: live for the first F ops, parked on row F-1 after
-                pl.BlockSpec(
-                    (1, d_tile), lambda d, i, s, v: (jnp.minimum(i, F - 1), d)
-                ),
-                # storage (aliased with output 0): the op's target row tile
-                pl.BlockSpec((1, d_tile), lambda d, i, s, v: (s[i], d)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, d_tile), lambda d, i, s, v: (s[i], d)),
-                pl.BlockSpec(
-                    (1, d_tile),
-                    lambda d, i, s, v: (jnp.maximum(i - F, 0) // L, d),
-                ),
-            ],
-        ),
+        kernel,
+        grid=(nf + ids.shape[0] // G,),
+        in_specs=[
+            pl.BlockSpec((G, 1), fill_step, memory_space=pltpu.SMEM),
+            pl.BlockSpec((G, D), fill_step),
+            pl.BlockSpec((G, L), bag_step, memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ] + ([pl.BlockSpec(memory_space=pl.ANY)] if quant else []),
+        out_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((G, D), bag_step),
+        ],
         out_shape=[
             jax.ShapeDtypeStruct((N, D), storage.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            jax.ShapeDtypeStruct(ids.shape[:1] + (D,), jnp.float32),
         ],
-        input_output_aliases={3: 0},  # (op_slot=0, op_valid=1, rows=2, st=3)
+        scratch_shapes=[s for s in scratch if s is not None],
+        input_output_aliases={3: 0},  # (slots=0, rows=1, ids=2, storage=3)
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(op_slot, op_valid, fill_rows, storage)
-    return storage_out, bags
-
-
-def _make_fused_q_kernel(F: int, L: int):
-    def _kernel(op_slot_ref, op_valid_ref, rows_ref, st_in_ref, scale_ref,
-                st_out_ref, bags_ref):
-        # Same op stream as _make_fused_kernel; gather steps dequantize
-        # in-kernel against the (1, 1) scale block of the op's target row.
-        # The scale array must ALREADY hold this cycle's fill scales (the
-        # shared wrapper scatters them before launch), so intra-kernel
-        # gathers of just-filled rows see payload (aliased RAW) and scale
-        # (pre-scattered) consistently.
-        del op_slot_ref, st_in_ref
-        i = pl.program_id(1)
-
-        @pl.when((i < F) & (op_valid_ref[i] == 1))
-        def _fill():
-            st_out_ref[...] = rows_ref[...].astype(st_out_ref.dtype)
-
-        @pl.when(i >= F)
-        def _gather():
-            l = (i - F) % L
-
-            @pl.when(l == 0)
-            def _init():
-                bags_ref[...] = jnp.zeros_like(bags_ref)
-
-            # FMA contraction is harmless here by the same exact-product
-            # argument as _gather_q_kernel (snapped scales)
-            bags_ref[...] += (
-                st_out_ref[...].astype(bags_ref.dtype) * scale_ref[0, 0]
-            )
-
-    return _kernel
-
-
-@functools.partial(jax.jit, static_argnames=("d_tile", "interpret"))
-def fill_gather_reduce_q(
-    storage: jax.Array,
-    scale: jax.Array,
-    fill_slots: jax.Array,
-    fill_rows: jax.Array,
-    slot_ids: jax.Array,
-    *,
-    d_tile: int = DEFAULT_D_TILE,
-    interpret: bool = False,
-):
-    """Fused fill + dequantizing gather for int8 storage: payload (N, D)
-    int8, scale (N, 1) fp32 (already updated with the fill rows' scales),
-    fill_rows (F, D) int8. Returns (filled payload, fp32 bags) — still ONE
-    pallas_call per cycle forward."""
-    nb, L = slot_ids.shape
-    (F,) = fill_slots.shape
-    N, D = storage.shape
-    d_tile = min(d_tile, D)
-    assert D % d_tile == 0, (D, d_tile)
-    assert F > 0 and nb * L > 0, (F, nb, L)  # empty guards live in ops.py
-    fslots = fill_slots.astype(jnp.int32)
-    valid = (fslots < N).astype(jnp.int32)
-    fslots = jnp.clip(fslots, 0, N - 1)
-    op_slot = jnp.concatenate([fslots, slot_ids.reshape(-1).astype(jnp.int32)])
-    op_valid = jnp.concatenate([valid, jnp.ones((nb * L,), jnp.int32)])
-    storage_out, bags = pl.pallas_call(
-        _make_fused_q_kernel(F, L),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(D // d_tile, F + nb * L),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, d_tile), lambda d, i, s, v: (jnp.minimum(i, F - 1), d)
-                ),
-                pl.BlockSpec((1, d_tile), lambda d, i, s, v: (s[i], d)),
-                pl.BlockSpec((1, 1), lambda d, i, s, v: (s[i], 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, d_tile), lambda d, i, s, v: (s[i], d)),
-                pl.BlockSpec(
-                    (1, d_tile),
-                    lambda d, i, s, v: (jnp.maximum(i - F, 0) // L, d),
-                ),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((N, D), storage.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
-        ],
-        # (op_slot=0, op_valid=1, rows=2, st=3, scale=4)
-        input_output_aliases={3: 0},
-        interpret=interpret,
-    )(op_slot, op_valid, fill_rows, storage, scale)
-    return storage_out, bags
+    )(*operands)
+    return storage_out, bags[:nb]

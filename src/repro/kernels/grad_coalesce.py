@@ -1,14 +1,13 @@
 """Pallas TPU kernel: gradient duplication + coalescing + scatter update
 (the paper's memory-bound backward primitive, §II-B Fig. 2(b)).
 
-The storage buffer is input/output-aliased; the scalar-prefetched slot ids
-drive the OUTPUT BlockSpec index map, so each grid step brings the target
-embedding row tile into VMEM, accumulates the bag's delta into it and lets
-Pallas write it back on block change. Duplicate rows within/across bags
-coalesce correctly because the TPU grid executes sequentially — later
-visits of the same row re-read the updated tile (read-modify-write), which
-is exactly the coalescing semantics of Fig. 2(b) without a separate sort
-pass.
+The storage buffer is input/output-aliased and stays in HBM
+(``memory_space=pl.ANY``). Every lookup is one explicit read-modify-write
+of its row: DMA the row in, wait, add the bag's delta, DMA it back, wait.
+The next lookup starts only after that write has landed, so duplicate rows
+within and across bags coalesce in flat bag-major order — exactly XLA's
+``at[].add`` — without a separate sort pass. Packed storages move the
+aligned block that holds the row (gather_reduce.row_block).
 
 The kernel body is a PURE add of a pre-rounded per-bag delta. The SGD
 scaling (``-lr * bag_grads``) is applied ONCE per bag in the wrapper
@@ -18,7 +17,7 @@ XLA's rounded-product-then-scatter-add. It also makes the kernel the
 generic coalescing scatter-add the custom_vjp backward reuses (scatter the
 bag cotangent into a zero buffer).
 
-grid = (n_bags, L, D // d_tile)
+grid = (ceil(n_bags / G),), G bags per step, one RMW per lookup.
 """
 from __future__ import annotations
 
@@ -26,53 +25,75 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_D_TILE = 128
+from repro.kernels.gather_reduce import (
+    G, _PARAMS, _pad_bags, _rows_at, put_row, row_block,
+)
 
 
-def _kernel(ids_ref, delta_ref, st_in_ref, st_out_ref):
-    # The output aliases the storage input, and the sequential TPU grid
-    # re-fetches the output block on revisit, so accumulating through the
-    # OUTPUT ref makes duplicate rows coalesce correctly (read-mod-write).
-    del st_in_ref
-    st_out_ref[...] += delta_ref[...].astype(st_out_ref.dtype)
+def _make_kernel(nb: int, L: int, R: int):
+    def kernel(ids_ref, delta_ref, st_in, st_hbm, buf, sem):
+        del st_in  # aliased with st_hbm
+        step = pl.program_id(0)
+
+        def bag(g, carry):
+            @pl.when(step * G + g < nb)  # padded bags do no work
+            def _bag():
+                # deltas ride in fp32 holding storage-dtype values: exact
+                delta = delta_ref[pl.ds(g, 1), :].astype(buf.dtype)
+
+                def lookup(l, carry):
+                    s = ids_ref[g, l]
+                    rows = _rows_at(st_hbm, s, R)
+                    rd = pltpu.make_async_copy(rows, buf, sem)
+                    rd.start()
+                    rd.wait()
+                    block = buf[...]
+                    buf[...] = put_row(block, s, block + delta, R)
+                    wr = pltpu.make_async_copy(buf, rows, sem)
+                    wr.start()
+                    wr.wait()
+                    return carry
+
+                lax.fori_loop(0, L, lookup, 0)
+
+            return carry
+
+        lax.fori_loop(0, G, bag, 0)
+
+    return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("d_tile", "interpret"))
-def scatter_add(
-    storage: jax.Array,
-    slot_ids: jax.Array,
-    bag_deltas: jax.Array,
-    *,
-    d_tile: int = DEFAULT_D_TILE,
-    interpret: bool = False,
-) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scatter_add(storage, slot_ids, bag_deltas, *, interpret=False):
     """storage (N, D); slot_ids (nb, L) int32; bag_deltas (nb, D) in the
     storage dtype. Adds each bag's delta to every row it looked up,
     coalescing duplicates in flat bag-major order (== XLA's ``at[].add``)."""
     nb, L = slot_ids.shape
     N, D = storage.shape
-    d_tile = min(d_tile, D)
-    assert D % d_tile == 0, (D, d_tile)
-    flat_ids = slot_ids.reshape(-1).astype(jnp.int32)
+    R = row_block(storage.dtype)
+    assert N % R == 0, (N, R)  # row padding lives in ops.py
+    ids = _pad_bags(slot_ids)
+    deltas = jnp.pad(
+        bag_deltas.astype(storage.dtype).astype(jnp.float32),
+        ((0, ids.shape[0] - nb), (0, 0)),
+    )
     return pl.pallas_call(
-        _kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nb, L, D // d_tile),
-            in_specs=[
-                pl.BlockSpec((1, d_tile), lambda b, l, d, ids: (b, d)),  # deltas
-                pl.BlockSpec(
-                    (1, d_tile), lambda b, l, d, ids: (ids[b * L + l], d)
-                ),  # storage (aliased with the output)
-            ],
-            out_specs=pl.BlockSpec(
-                (1, d_tile), lambda b, l, d, ids: (ids[b * L + l], d)
-            ),
-        ),
+        _make_kernel(nb, L, R),
+        grid=(ids.shape[0] // G,),
+        in_specs=[
+            pl.BlockSpec((G, L), lambda i: (i, 0), memory_space=pltpu.SMEM),
+            pl.BlockSpec((G, D), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((N, D), storage.dtype),
-        input_output_aliases={2: 0},  # storage (ids=0, deltas=1) -> output 0
+        scratch_shapes=[pltpu.VMEM((R, D), storage.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={2: 0},  # (ids=0, deltas=1, storage=2)
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(flat_ids, bag_deltas, storage)
+    )(ids, deltas, storage)

@@ -1,19 +1,22 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (CPU validation per the brief); on a
-real TPU backend the kernels compile natively. Wrappers own everything the
-raw kernels assert away:
+``interpret`` defaults to False on a TPU backend, where the kernels compile
+natively, and to True on the CPU backend, where the tests run. Any other
+backend is an error: a kernel never runs interpreted on an accelerator.
+Wrappers own everything the raw kernels assert away:
 
   * natural shapes — leading batch/table dims are flattened to (nb, L) and
     restored on the way out;
   * empty-operand cycles — zero bags, zero lookups or zero fill rows skip
     the ``pallas_call`` entirely (the same discipline as the pipeline's
     empty-dispatch guard);
-  * ragged lane dims — when ``D % d_tile != 0`` (possible only for
-    D > 128 and not a multiple of 128) the lane axis is zero-padded up to
-    the tile and sliced back after. This is a documented correctness
-    fallback: it copies storage and costs the in-place alias, but no
-    shipped config is ragged (D in {8, 32, 128});
+  * packed row counts — a packed (bf16/fp16/int8) storage moves in aligned
+    ``row_block``-row DMA blocks, so a row count that is not a multiple of
+    that block is zero-padded and sliced back after. That copies storage
+    and costs the in-place alias, so size packed storages to the block;
+  * float16 on the chip — Mosaic cannot load float16 vectors ("Invalid
+    vector type for load" on v5e), so a float16 storage raises on TPU
+    (``kernel="xla"`` serves fp16 there);
   * differentiation — ``gather_reduce`` and ``fill_gather_reduce`` carry a
     ``jax.custom_vjp`` whose backward reuses the coalescing scatter-add
     kernel (grad_coalesce), so ``jax.grad`` straight through the kernel
@@ -38,42 +41,44 @@ from repro.kernels import ref as _ref
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for TPU and are interpreted on CPU; "
+            f"backend {backend!r} is neither (use kernel='xla')"
+        )
+    return backend == "cpu"
 
 
-def _lane_pad(D: int) -> int:
-    """Zero-pad amount taking the lane dim to a d_tile multiple (0 = none)."""
-    return (-D) % min(_gr.DEFAULT_D_TILE, D)
-
-
-def _pad_lanes(x, pad: int):
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+def _rows(interpret, storage):
+    """Storage as the kernels take it: padded to whole DMA row blocks.
+    Returns (storage, original row count)."""
+    if not interpret and storage.dtype == jnp.float16:
+        raise NotImplementedError(
+            "kernel='pallas' cannot take float16 storage on TPU: Mosaic "
+            "cannot load float16 vectors. Use kernel='xla' for fp16, or "
+            "precision='int8'."
+        )
+    n = storage.shape[0]
+    pad = (-n) % _gr.row_block(storage.dtype)
+    if pad:
+        storage = jnp.pad(storage, ((0, pad), (0, 0)))
+    return storage, n
 
 
 # --------------------------------------------------------------------- #
 # forward: gather + bag reduce
 # --------------------------------------------------------------------- #
-def _gather_call(interpret, storage, flat_slots):
-    pad = _lane_pad(storage.shape[1])
-    if pad:
-        out = _gr.gather_reduce(
-            _pad_lanes(storage, pad), flat_slots, interpret=interpret
-        )
-        return out[:, : storage.shape[1]]
-    return _gr.gather_reduce(storage, flat_slots, interpret=interpret)
+def _gather_call(interpret, storage, flat_slots, scale=None):
+    storage, _ = _rows(interpret, storage)
+    return _gr.gather_reduce(storage, flat_slots, scale, interpret=interpret)
 
 
 def _scatter_call(interpret, storage, flat_slots, bag_deltas):
-    pad = _lane_pad(storage.shape[1])
-    if pad:
-        out = _gc.scatter_add(
-            _pad_lanes(storage, pad),
-            flat_slots,
-            _pad_lanes(bag_deltas, pad),
-            interpret=interpret,
-        )
-        return out[:, : storage.shape[1]]
-    return _gc.scatter_add(storage, flat_slots, bag_deltas, interpret=interpret)
+    storage, n = _rows(interpret, storage)
+    return _gc.scatter_add(
+        storage, flat_slots, bag_deltas, interpret=interpret
+    )[:n]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
@@ -112,33 +117,19 @@ def gather_reduce(storage, slot_ids, *, interpret=None):
     return out.reshape(*lead, D).astype(storage.dtype)
 
 
-def _gather_q_call(interpret, storage, scale, flat_slots):
-    pad = _lane_pad(storage.shape[1])
-    if pad:
-        out = _gr.gather_reduce_q(
-            _pad_lanes(storage, pad), scale, flat_slots, interpret=interpret
-        )
-        return out[:, : storage.shape[1]]
-    return _gr.gather_reduce_q(storage, scale, flat_slots, interpret=interpret)
-
-
 def gather_reduce_q(storage, scale, slot_ids, *, interpret=None):
     """Quantized-storage gather -> fp32 bags (no cast back to the storage
     dtype: the MLP consumes fp32). ``scale=None`` means dequantization is
-    the exact widening cast (fp16 storage) and the plain gather kernel —
-    whose accumulator is already fp32 — is the quantized kernel; an (N, 1)
-    ``scale`` selects the int8 dequantize-in-kernel variant."""
+    the exact widening cast (fp16 storage) and the plain gather — whose
+    accumulator is already fp32 — is the quantized kernel; an (N, 1)
+    ``scale`` makes the kernel dequantize int8 rows in-kernel."""
     interpret = _interpret_default() if interpret is None else interpret
     lead = slot_ids.shape[:-1]
     L = slot_ids.shape[-1]
     D = storage.shape[1]
     if L == 0 or slot_ids.size == 0:  # empty cycle: no dispatch
         return jnp.zeros(lead + (D,), jnp.float32)
-    flat = slot_ids.reshape(-1, L)
-    if scale is None:
-        out = _gather_call(interpret, storage, flat)
-    else:
-        out = _gather_q_call(interpret, storage, scale, flat)
+    out = _gather_call(interpret, storage, slot_ids.reshape(-1, L), scale)
     return out.reshape(*lead, D)
 
 
@@ -159,7 +150,6 @@ def coalesce_deltas(buf, slot_ids, deltas, *, interpret=None):
         interpret, buf, slot_ids.reshape(-1, L),
         deltas.reshape(-1, D).astype(buf.dtype),
     )
-
 
 
 def coalesce_apply(storage, slot_ids, bag_grads, lr, *, interpret=None):
@@ -185,28 +175,20 @@ def fill(storage, fill_slots, rows, *, interpret=None):
     interpret = _interpret_default() if interpret is None else interpret
     if fill_slots.size == 0:  # empty cycle: no dispatch
         return storage
-    pad = _lane_pad(storage.shape[1])
-    if pad:
-        out = _gr.fill(
-            _pad_lanes(storage, pad), fill_slots, _pad_lanes(rows, pad),
-            interpret=interpret,
-        )
-        return out[:, : storage.shape[1]]
-    return _gr.fill(storage, fill_slots, rows, interpret=interpret)
+    padded, n = _rows(interpret, storage)
+    # sentinels are == n; re-point them past the padded rows too
+    slots = jnp.where(fill_slots < n, fill_slots, padded.shape[0])
+    return _gr.fill(padded, slots, rows, interpret=interpret)[:n]
 
 
-def _fused_call(interpret, storage, fill_slots, fill_rows, flat_slots):
-    pad = _lane_pad(storage.shape[1])
-    if pad:
-        st, bags = _gr.fill_gather_reduce(
-            _pad_lanes(storage, pad), fill_slots, _pad_lanes(fill_rows, pad),
-            flat_slots, interpret=interpret,
-        )
-        D = storage.shape[1]
-        return st[:, :D], bags[:, :D]
-    return _gr.fill_gather_reduce(
-        storage, fill_slots, fill_rows, flat_slots, interpret=interpret
+def _fused_call(interpret, storage, fill_slots, fill_rows, flat_slots,
+                scale=None):
+    padded, n = _rows(interpret, storage)
+    slots = jnp.where(fill_slots < n, fill_slots, padded.shape[0])
+    st, bags = _gr.fill_gather_reduce(
+        padded, slots, fill_rows, flat_slots, scale, interpret=interpret
     )
+    return st[:n], bags
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
@@ -268,27 +250,12 @@ def fill_gather_reduce(storage, fill_slots, fill_rows, slot_ids, *,
     return st, bags.reshape(*lead, D).astype(storage.dtype)
 
 
-def _fused_q_call(interpret, storage, scale, fill_slots, fill_rows,
-                  flat_slots):
-    pad = _lane_pad(storage.shape[1])
-    if pad:
-        st, bags = _gr.fill_gather_reduce_q(
-            _pad_lanes(storage, pad), scale, fill_slots,
-            _pad_lanes(fill_rows, pad), flat_slots, interpret=interpret,
-        )
-        D = storage.shape[1]
-        return st[:, :D], bags[:, :D]
-    return _gr.fill_gather_reduce_q(
-        storage, scale, fill_slots, fill_rows, flat_slots, interpret=interpret
-    )
-
-
 def fill_gather_reduce_q(storage, scale, fill_slots, fill_rows, slot_ids, *,
                          interpret=None):
     """Fused quantized fill + gather -> (payload storage, fp32 bags).
     ``scale=None`` is the fp16 path (plain fused kernel, fp32 accumulator);
     an (N, 1) ``scale`` — already scatter-updated with this cycle's fill
-    scales — selects the int8 dequantize-in-kernel fused variant. No
+    scales — makes the fused kernel dequantize int8 rows in-kernel. No
     custom_vjp: the production step takes bag cotangents explicitly and the
     quantized backward runs through ``coalesce_deltas`` + the requantize
     epilogue (core/quantize.py)."""
@@ -305,13 +272,10 @@ def fill_gather_reduce_q(storage, scale, fill_slots, fill_rows, slot_ids, *,
         return storage, gather_reduce_q(
             storage, scale, slot_ids, interpret=interpret
         )
-    flat = slot_ids.reshape(-1, L)
-    if scale is None:
-        st, bags = _fused_call(interpret, storage, fill_slots, fill_rows, flat)
-    else:
-        st, bags = _fused_q_call(
-            interpret, storage, scale, fill_slots, fill_rows, flat
-        )
+    st, bags = _fused_call(
+        interpret, storage, fill_slots, fill_rows, slot_ids.reshape(-1, L),
+        scale,
+    )
     return st, bags.reshape(*lead, D)
 
 

@@ -211,7 +211,10 @@ def main():
         "Perfetto / chrome://tracing)",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
     from repro.launch.train import obs_export, obs_setup
+
+    setup_compile_cache()
 
     tracer, metrics = obs_setup(args.trace_out, args.metrics_out)
     try:

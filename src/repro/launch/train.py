@@ -60,7 +60,7 @@ def train_lm(args):
     from repro.configs.base import ShapeSpec
 
     shape = (
-        ShapeSpec("smoke", args.seq_len, args.batch, "train")
+        ShapeSpec("smoke", args.seq_len, args.batch or 8, "train")
         if args.smoke
         else ShapeSpec("train_4k", 4096, 256, "train")
     )
@@ -254,6 +254,8 @@ def train_dlrm(args):
                 if args.smoke
                 else get_config("dlrm-scratchpipe")
             )
+        if args.rows:  # host-RAM cut of the uniform config's table height
+            cfg = dataclasses.replace(cfg, rows_per_table=args.rows)
         group = TableGroup.from_config(cfg)
         batch = args.batch or cfg.batch_size
     if args.precision != "fp32":
@@ -315,7 +317,12 @@ def train_dlrm(args):
         budgets = group.precision_slot_budgets(slots, min_per_table=floor)
         kw = {"num_slots": slots, "table_group": group, "slot_budgets": budgets}
     else:
-        # uniform paper config: keep the seed-equivalent global slot pool
+        # uniform paper config: one global slot pool, at least the §VI-D
+        # worst-case window working set (6 batches of B*T*L lookups)
+        floor = group.window_floor(
+            batch * cfg.lookups_per_table * group.num_tables
+        )
+        slots = max(slots, min(rows, floor))
         kw = {"num_slots": slots}
     if args.runtime == "scratchpipe":
         kw.update(past_window=cfg.past_window, future_window=cfg.future_window)
@@ -440,7 +447,19 @@ def main():
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument(
+        "--batch",
+        type=int,
+        default=None,
+        help="mini-batch size (default: the config's; 8 for LM smoke runs)",
+    )
+    ap.add_argument(
+        "--rows",
+        type=int,
+        default=None,
+        help="rows per table of the uniform DLRM config (default: the "
+        "config's; the paper's 10M x 8 tables is a 40 GB host tier)",
+    )
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
@@ -471,7 +490,7 @@ def main():
         default="xla",
         help="embedding-primitive implementation: 'pallas' runs the fused "
         "fill+gather+reduce forward and coalesce+scatter backward cycle "
-        "kernels (interpret-mode off-TPU; bit-identical to 'xla')",
+        "kernels (native on TPU, interpreted on the CPU backend)",
     )
     ap.add_argument(
         "--precision",
@@ -589,6 +608,12 @@ def main():
         "scratchpipe", "strawman"
     ):
         ap.error("--supervise/--chaos cover the scratchpipe-family runtimes")
+    if args.rows and (args.tables or args.trace):
+        ap.error("--rows sizes the uniform config; --tables/--trace set "
+                 "their own table heights")
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     tracer, metrics = obs_setup(
         args.trace_out, args.metrics_out, jax_annotations=args.jax_annotations
     )

@@ -1,15 +1,6 @@
 import jax
 import pytest
 
-# jax.sharding.AxisType + jax.set_mesh landed after jax 0.4.x; the LM-side
-# sharded tests need them. Gate (skip) instead of hard-failing so the
-# cache-stack suite still runs on older jax builds.
-HAS_MODERN_MESH = hasattr(jax.sharding, "AxisType") and hasattr(jax, "set_mesh")
-requires_modern_mesh = pytest.mark.skipif(
-    not HAS_MODERN_MESH,
-    reason="jax.sharding.AxisType / jax.set_mesh unavailable in this jax",
-)
-
 
 @pytest.fixture(scope="session")
 def mesh1():
@@ -18,8 +9,6 @@ def mesh1():
     (Real multi-device partitioning is tested in tests/test_multidevice.py
     via a subprocess with --xla_force_host_platform_device_count, so the
     main process keeps the default 1-device view per the project brief.)"""
-    if not HAS_MODERN_MESH:
-        pytest.skip("jax.sharding.AxisType / jax.set_mesh unavailable in this jax")
     return jax.make_mesh(
         (1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
     )

@@ -122,3 +122,19 @@ def test_sgd_momentum():
     np.testing.assert_allclose(
         float(p["w"][0]), 1.0 - 0.1 - 0.1 * 1.9, rtol=1e-6
     )
+
+
+def test_host_table_chunked_init_equals_one_draw(monkeypatch):
+    """The host tier draws its init in row chunks to bound host RAM; the
+    values must be those of a single (rows, dim) draw, bit for bit."""
+    from repro.core import host_table
+
+    rows, dim, seed = 1003, 128, 3
+    monkeypatch.setattr(host_table, "_INIT_CHUNK_VALUES", 100 * dim)
+    got = host_table.HostEmbeddingTable(rows, dim, seed=seed).data
+    rng = np.random.default_rng(seed)
+    want = (rng.standard_normal((rows, dim)) * (1.0 / np.sqrt(dim))).astype(
+        np.float32
+    )
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))

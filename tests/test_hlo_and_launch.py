@@ -115,3 +115,27 @@ def test_cached_embedding_lm_matches_full_embedding(mesh1):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32), atol=2e-4
         )
+
+
+@pytest.mark.parametrize("env_dir", [None, "/cache/from/env"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the cache
+    sits at a fixed path inside the checkout (a moving path never hits)."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.setup_compile_cache()
+        if env_dir is None:
+            want = str(compile_cache.REPO_ROOT / ".jax_cache")
+            assert got == want == jax.config.jax_compilation_cache_dir
+            assert (compile_cache.REPO_ROOT / "pyproject.toml").exists()
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

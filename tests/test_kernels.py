@@ -143,6 +143,27 @@ def test_fill_drop_mode_sentinel(dtype):
     )
 
 
+def test_fp16_storage_refused_when_native():
+    """Mosaic cannot load float16 on the chip: a native (non-interpreted)
+    call must say so, not fall back to interpret mode or the reference."""
+    st_ = jnp.zeros((16, 128), jnp.float16)
+    ids = jnp.zeros((2, 3), jnp.int32)
+    with pytest.raises(NotImplementedError, match="float16"):
+        ops.gather_reduce_q(st_, None, ids, interpret=False)
+
+
+def test_interpret_only_on_cpu(monkeypatch):
+    """Kernels interpret on the CPU backend, compile on TPU, and refuse any
+    other backend instead of silently interpreting there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret_default() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret_default() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret_default()
+
+
 def test_fill_empty_operands():
     st_ = jnp.asarray(RNG.standard_normal((8, 128)).astype(np.float32))
     out = ops.fill(

@@ -107,12 +107,6 @@ print("ALL-MULTIDEVICE-OK")
 
 @pytest.mark.slow
 def test_multidevice_subprocess():
-    from conftest import HAS_MODERN_MESH
-
-    if not HAS_MODERN_MESH:
-        pytest.skip(
-            "subprocess script needs jax.sharding.AxisType / jax.set_mesh"
-        )
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run(

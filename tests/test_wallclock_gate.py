@@ -27,7 +27,7 @@ RUNNER = {
     "machine": "x86_64",
     "cpus": 2,
     "python": "3.11.8",
-    "jax": "0.4.37",
+    "jax": "0.9.0",
     "backend": "cpu",
 }
 
