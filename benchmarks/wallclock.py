@@ -6,7 +6,9 @@ container cannot exhibit a 900 GB/s HBM). This module is the other column of
 the methodology: it measures what actually runs, end to end, on this
 container — steps/s through the full runtime hot loop (planner, host
 gathers/scatters, device dispatches, train), the per-stage ms breakdown from
-``StepStats.stage_times``, and the [Plan] controller cost in µs/batch.
+the main thread's stage spans (a ``repro.obs.Tracer`` passed to the runtime,
+read through ``Tracer.totals()``), and the [Plan] controller cost in
+µs/batch.
 Model-derived ms and measured steps/s are different columns and are never
 mixed.
 
@@ -157,8 +159,13 @@ def _sharded_train_fn(num_tables: int):
     return fn
 
 
+#: the main thread's top-level stage spans; together they cover the cycle
+STAGE_SPANS = ("plan", "collect", "exchange", "insert_host", "insert_fill",
+               "train", "retire")
+
+
 def build_runtime(design: str, mode: str, group, host, trainer,
-                  batches_for_profile) -> object:
+                  batches_for_profile, tracer=None) -> object:
     from repro.core.runtime import make_runtime
     from repro.data.synthetic import TraceConfig, hot_ids_global
 
@@ -168,14 +175,14 @@ def build_runtime(design: str, mode: str, group, host, trainer,
     planner = "device" if mode == "device" else "host"
     if design in ("scratchpipe", "strawman"):
         kw = {"num_slots": slots, "executor": executor, "planner": planner,
-              "record_stage_times": True,
+              "tracer": tracer,
               "kernel": _mode_kernel(mode)}  # runtime-side [Insert] fills
         if mode in ("fast", "device", "pallas"):
             kw["fused_train_fn"] = trainer.fused_train_fn
         return make_runtime(design, host, trainer.train_fn, **kw)
     if design == "sharded":
         kw = {"num_slots": slots, "table_group": group, "executor": executor,
-              "record_stage_times": True, "planner": planner}
+              "tracer": tracer, "planner": planner}
         return make_runtime(
             design, host, _sharded_train_fn(group.num_tables), **kw
         )
@@ -213,6 +220,7 @@ def measure_cell(design: str, scenario: str, mode: str, warmup: int,
                  steps: int) -> dict:
     import jax
 
+    from repro import obs
     from repro.core.dlrm_runtime import DLRMTrainer
     from repro.core.host_table import HostEmbeddingTable
     from repro.core.table_group import TableGroup
@@ -225,7 +233,9 @@ def measure_cell(design: str, scenario: str, mode: str, warmup: int,
     host = HostEmbeddingTable(group.total_rows, cfg.embed_dim, seed=1)
     kernel = _mode_kernel(mode)
     trainer = DLRMTrainer(cfg, jax.random.key(0), lr=0.05, kernel=kernel)
-    runtime = build_runtime(design, mode, group, host, trainer, profile)
+    tracer = obs.Tracer()
+    runtime = build_runtime(design, mode, group, host, trainer, profile,
+                            tracer)
 
     stream = LookaheadStream(iter(items))
     it = iter(stream)
@@ -235,6 +245,7 @@ def measure_cell(design: str, scenario: str, mode: str, warmup: int,
     _sync(runtime, trainer)
 
     n_before = len(runtime.stats)
+    spans_before = tracer.totals()
     t0 = time.perf_counter()
     for _ in range(steps):
         ids, batch = next(it)
@@ -249,24 +260,18 @@ def measure_cell(design: str, scenario: str, mode: str, warmup: int,
                     p.drain_one_cycle()
     _sync(runtime, trainer)
     elapsed = time.perf_counter() - t0
+    spans = tracer.totals()
 
     stats = runtime.stats[n_before:]
     n_trained = len(stats)
-    stage_ms = None
-    # the first (past+1+future) retired entries ran their early stages
-    # BEFORE the timer edge (they were in flight at the warmup boundary) —
-    # excluding them keeps mean stage sums comparable to ms_per_step
-    whole = stats[6:] if len(stats) > 9 else stats
-    timed = [s for s in whole if getattr(s, "stage_times", None)]
-    if timed:
-        keys = sorted({k for s in timed for k in s.stage_times})
-        stage_ms = {
-            k: round(
-                1e3 * float(np.mean([s.stage_times.get(k, 0.0) for s in timed])),
-                4,
-            )
-            for k in keys
-        }
+    # main-thread seconds of each stage span between the timer edges, per
+    # step trained in the window
+    stage_ms = {
+        name: round(1e3 * (spans[key] - spans_before.get(key, 0.0))
+                    / max(n_trained, 1), 4)
+        for name in STAGE_SPANS
+        if (key := ("MainThread", name)) in spans
+    } or None
     hit = float(np.mean([s.hit_rate for s in stats])) if stats else 0.0
     close = getattr(runtime, "close", None)
     if close is not None:

@@ -61,7 +61,9 @@ The runtime also keeps per-tier byte counters ([Collect]/[Insert] host bytes,
 [Exchange] PCIe bytes, [Train] HBM bytes) — these feed the calibrated
 bandwidth model reproducing the paper's latency figures. Counters always
 track LOGICAL (unpadded) bytes and are updated unconditionally, so both
-executors and both dispatch paths report identical traffic.
+executors and both dispatch paths report identical traffic. What actually
+crosses the link, padding included, is counted only with a metrics registry
+installed (``cache.h2d_rows``/``_bytes``, ``cache.d2h_rows``/``_bytes``).
 
 Mixed precision (``precision="fp32"|"fp16"|"int8"``, core/quantize.py): the
 host table keeps fp32 masters; the scratchpad holds quantized replicas.
@@ -77,7 +79,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import (
     Any, Callable, Deque, Dict, Iterator, List, Optional, Sequence, Tuple,
@@ -112,13 +113,6 @@ class StepStats:
     n_evict: int
     hit_lookups: int = 0  # lookup-level (non-unique) hit count
     by_table: Any = None  # per-table {hits, misses} (multi-table runs only)
-    # DEPRECATED: main-thread seconds per stage only. Under
-    # executor="overlapped" this field cannot see worker/d2h time (the
-    # submit returns immediately, so "collect"/"insert" record enqueue cost
-    # and the d2h copy is charged nowhere). Use a repro.obs.Tracer — its
-    # spans are recorded on the thread that does the work, and
-    # Tracer.totals() gives (thread, stage) -> seconds attribution.
-    stage_times: Optional[Dict[str, float]] = None
     aux: Any = None
 
     @property
@@ -138,7 +132,6 @@ class _InFlight:
     evicted_host: Optional[np.ndarray] = None  # [Exchange] d2h
     evicted_host_f: Optional[SupervisedOp] = None  # overlapped: pending d2h
     stage: int = 0  # stages completed: 1=planned .. 4=inserted
-    times: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 #: PlanResult fields serialized per in-flight entry by the mid-stream
@@ -167,6 +160,13 @@ def _d2h_slice(arr, n: int):
     return np.asarray(arr)[:n]
 
 
+def _link_size(block) -> Tuple[int, int]:
+    """(rows, bytes) of a padded row block that crosses the host-device
+    link: one array, or an int8 scratchpad's (payload, scale) pair."""
+    parts = block if isinstance(block, tuple) else (block,)
+    return int(parts[0].shape[0]), sum(int(a.nbytes) for a in parts)
+
+
 class ScratchPipe:
     def __init__(
         self,
@@ -185,7 +185,6 @@ class ScratchPipe:
         executor: str = "sync",
         fused_train_fn: Optional[Callable] = None,
         memoize_plan: bool = True,
-        record_stage_times: bool = False,
         planner: str = "host",
         pad_buckets: Optional[Sequence[int]] = None,
         kernel: str = "xla",
@@ -202,7 +201,6 @@ class ScratchPipe:
         self.host = host_table
         self.train_fn = train_fn
         self.fused_train_fn = fused_train_fn
-        self.record_stage_times = record_stage_times
         self.pipelined = pipelined
         self.executor = executor
         self.planner_placement = planner
@@ -253,6 +251,10 @@ class ScratchPipe:
             slot_ranges = table_group.slot_ranges(budgets)
         else:
             row_offsets = slot_ranges = None
+        # -- telemetry (strictly opt-in; see repro.obs) --------------------- #
+        # Resolved ONCE here; with both unset the hot loop sees only
+        # `is None` branches and the shared NULL_SPAN singleton.
+        self._tracer, self._metrics = obs_resolve(tracer, metrics)
         if planner == "device":
             # [Plan] state lives on-accelerator; raw ids are what cross h2d
             # each cycle, and the dense id->slot translate never runs on host
@@ -278,6 +280,7 @@ class ScratchPipe:
                 row_offsets=row_offsets,
                 slot_ranges=slot_ranges,
                 memoize=memoize_plan,
+                tracer=self._tracer,
             )
         import jax.numpy as jnp
 
@@ -310,10 +313,6 @@ class ScratchPipe:
             self._d2h_pool = ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="scratchpipe-d2h"
             )
-        # -- telemetry (strictly opt-in; see repro.obs) --------------------- #
-        # Resolved ONCE here; with both unset the hot loop sees only
-        # `is None` branches and the shared NULL_SPAN singleton.
-        self._tracer, self._metrics = obs_resolve(tracer, metrics)
         # Pool-submitted work is span-wrapped at construction (not per
         # cycle), so spans land on the worker/d2h thread that runs them and
         # the on-path allocates no closures in the loop either.
@@ -331,7 +330,7 @@ class ScratchPipe:
         self._d2h_slice_fn = _d2h_slice
         if self._tracer is not None:
             self._gather_fn = self._tracer.wrap(
-                "collect.gather", self.host.gather, cat="host"
+                "collect.gather", self._gather_fn, cat="host"
             )
             self._writeback_fn = self._tracer.wrap(
                 "insert.writeback", self._writeback, cat="host"
@@ -363,7 +362,9 @@ class ScratchPipe:
         self._mc = {
             k: m.counter(f"cache.{k}", **labels)
             for k in ("cycles", "lookups", "unique", "hits", "misses",
-                      "evicts", "fills")
+                      "evicts", "fills",
+                      # rows and bytes handed to the link, padding included
+                      "h2d_rows", "h2d_bytes", "d2h_rows", "d2h_bytes")
         }
         self._tbl_counters = None
         if self.table_group is not None:
@@ -579,7 +580,6 @@ class ScratchPipe:
     # stages
     # ------------------------------------------------------------------ #
     def _stage_plan(self, entry: _InFlight, lookahead: List[np.ndarray]):
-        t0 = time.perf_counter()
         with self._span("plan"):
             entry.plan = self.planner.plan(entry.ids, lookahead)
             if self._d2h_pool is not None and hasattr(
@@ -588,10 +588,8 @@ class ScratchPipe:
                 # device planner + overlapped executor: pull the miss/evict
                 # ids back on the d2h worker so the sync overlaps [Train]
                 entry.plan.start_materialize(self._d2h_pool, tracer=self._tracer)
-        entry.times["plan"] = time.perf_counter() - t0
 
     def _stage_collect(self, entry: _InFlight):
-        t0 = time.perf_counter()
         with self._span("collect"):
             p = entry.plan
             if p.miss_ids.size:
@@ -608,23 +606,28 @@ class ScratchPipe:
                     self.storage, pad_index(p.evict_slots, 0, self.pad_buckets)
                 )
             self.hbm.read += p.evict_slots.size * self._row_bytes
-        entry.times["collect"] = time.perf_counter() - t0
 
     def _stage_exchange(self, entry: _InFlight):
-        t0 = time.perf_counter()
         with self._span("exchange"):
             p = entry.plan
+            mc = self._mc
             if p.miss_ids.size:
                 rows = (
                     self._op_result(entry.host_rows_f)
                     if entry.host_rows_f is not None
                     else entry.host_rows
                 )
-                if isinstance(rows, tuple):  # int8: (payload, scale) pair
-                    rows = tuple(pad_rows(r, self.pad_buckets) for r in rows)
-                else:
-                    rows = pad_rows(rows, self.pad_buckets)
-                entry.fetched_dev = jax.device_put(rows)  # h2d
+                with self._span("exchange.pad", cat="host"):
+                    if isinstance(rows, tuple):  # int8: (payload, scale) pair
+                        rows = tuple(pad_rows(r, self.pad_buckets) for r in rows)
+                    else:
+                        rows = pad_rows(rows, self.pad_buckets)
+                with self._span("exchange.h2d", cat="h2d"):
+                    entry.fetched_dev = jax.device_put(rows)
+                if mc is not None:
+                    n, nbytes = _link_size(rows)
+                    mc["h2d_rows"].inc(n)
+                    mc["h2d_bytes"].inc(nbytes)
             n_evict = int(p.evict_slots.size)
             if n_evict:
                 if self._d2h_pool is not None:
@@ -639,13 +642,16 @@ class ScratchPipe:
                     entry.evicted_host = self._d2h_slice_fn(
                         entry.evicted_dev, n_evict
                     )  # d2h
+                if mc is not None:
+                    # the d2h copies the whole padded read back
+                    n, nbytes = _link_size(entry.evicted_dev)
+                    mc["d2h_rows"].inc(n)
+                    mc["d2h_bytes"].inc(nbytes)
             self.pcie.written += p.miss_ids.size * self._row_bytes
             self.pcie.read += p.evict_slots.size * self._row_bytes
-        entry.times["exchange"] = time.perf_counter() - t0
 
     def _stage_insert_host(self, entry: _InFlight):
         """[Insert], host half: write evicted (dirty, trained) rows back."""
-        t0 = time.perf_counter()
         with self._span("insert_host"):
             p = entry.plan
             if p.evict_ids.size:
@@ -654,14 +660,13 @@ class ScratchPipe:
                         self._writeback_fn, p.evict_ids, entry.evicted_host_f
                     )
                 else:
-                    self.host.scatter(
-                        p.evict_ids, self._dequant(entry.evicted_host)
-                    )
-        entry.times["insert"] = time.perf_counter() - t0
+                    with self._span("insert.writeback", cat="host"):
+                        self.host.scatter(
+                            p.evict_ids, self._dequant(entry.evicted_host)
+                        )
 
     def _stage_insert_fill(self, entry: _InFlight):
         """[Insert], device half: fill fetched rows into their slots."""
-        t0 = time.perf_counter()
         with self._span("insert_fill"):
             p = entry.plan
             if p.fill_slots.size:
@@ -672,19 +677,15 @@ class ScratchPipe:
                     kernel=self.kernel,
                 )
             self.hbm.written += p.fill_slots.size * self._row_bytes
-        entry.times["insert"] = entry.times.get("insert", 0.0) + (
-            time.perf_counter() - t0
-        )
 
     def _stage_train(
         self, entry: _InFlight, fused_entry: Optional[_InFlight] = None
     ) -> StepStats:
-        t0 = time.perf_counter()
         with self._span("train"):
-            return self._train_body(entry, fused_entry, t0)
+            return self._train_body(entry, fused_entry)
 
     def _train_body(
-        self, entry: _InFlight, fused_entry: Optional[_InFlight], t0: float
+        self, entry: _InFlight, fused_entry: Optional[_InFlight]
     ) -> StepStats:
         p = entry.plan
         if fused_entry is not None:
@@ -700,7 +701,6 @@ class ScratchPipe:
                 entry.batch,
             )
             self.hbm.written += fp.fill_slots.size * self._row_bytes
-            fused_entry.times["insert"] = fused_entry.times.get("insert", 0.0)
         else:
             self.storage, aux = self.train_fn(self.storage, p.slots, entry.batch)
         # [Train] HBM traffic: gather reads + coalesced scatter read-mod-write
@@ -710,7 +710,6 @@ class ScratchPipe:
         by_table = None
         if p.hits_by_table is not None:
             by_table = {"hits": p.hits_by_table, "misses": p.misses_by_table}
-        entry.times["train"] = time.perf_counter() - t0
         st = StepStats(
             step=p.step,
             n_lookups=int(p.slots.size),
@@ -720,7 +719,6 @@ class ScratchPipe:
             n_evict=int(p.evict_slots.size),
             hit_lookups=int(p.slots.size),  # always-hit at [Train] (§IV)
             by_table=by_table,
-            stage_times=dict(entry.times) if self.record_stage_times else None,
             aux=aux,
         )
         self._stats.append(st)
@@ -756,29 +754,33 @@ class ScratchPipe:
         it = iter(stream)
         draining = False
         while True:
-            if not draining:
-                # Streams exposing ``exhausted`` (LookaheadStream,
-                # TraceReplayStream) are asked directly — a short look-ahead
-                # window near the end already told them, so the drain
-                # decision never rests on a sentinel next() probe.
-                if getattr(stream, "exhausted", False):
-                    draining = True
-                else:
-                    try:
-                        ids, batch = next(it)
-                    except StopIteration:
-                        draining = True
-                    else:
+            with self._span("cycle"):
+                if not draining:
+                    # Streams exposing ``exhausted`` (LookaheadStream,
+                    # TraceReplayStream) are asked directly — a short
+                    # look-ahead window near the end already told them, so
+                    # the drain decision never rests on a sentinel next()
+                    # probe.
+                    draining = bool(getattr(stream, "exhausted", False))
+                if not draining:
+                    # the wait for the data stream, as the program sees it
+                    with self._span("input", cat="input"):
+                        try:
+                            ids, batch = next(it)
+                        except StopIteration:
+                            draining = True
+                        else:
+                            la = (
+                                lookahead_fn(self.future_window)
+                                if lookahead_fn
+                                else []
+                            )
+                    if not draining:
                         entry = _InFlight(np.asarray(ids), batch)
-                        la = (
-                            lookahead_fn(self.future_window)
-                            if lookahead_fn
-                            else []
-                        )
                         self._stage_plan(entry, la)
                         entry.stage = 1
                         self._window.append(entry)
-            self._advance_cycle(out)
+                self._advance_cycle(out)
             if draining and not self._window:
                 break
         self._barrier()
@@ -816,6 +818,11 @@ class ScratchPipe:
         for s in (1, 2, 3):
             if s in by_stage:
                 by_stage[s].stage = s + 1
+        if e4 is not None:
+            # the last references to the retired batch: its ids, plan, host
+            # rows and device buffers are released here
+            with self._span("retire", cat="host"):
+                del by_stage, e4
 
     # -- incremental driving (lockstep multi-shard execution, §VI-G) ------- #
     def run_one_cycle(self, ids, batch, lookahead_fn=None) -> Optional[StepStats]:

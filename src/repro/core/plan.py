@@ -50,6 +50,8 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import NULL_SPAN
+
 _INT32_MAX = np.iinfo(np.int32).max
 
 # ---------------------------------------------------------------------------
@@ -217,6 +219,13 @@ class Planner:
     unique/probe from scratch — the pre-fast-path behavior, kept for the
     identity tests and as an escape hatch for callers that mutate batch
     arrays in place).
+
+    ``tracer`` (a :class:`repro.obs.Tracer`, or None for no spans) times
+    two parts of [Plan]: ``plan.unique`` around each ``np.unique`` (a
+    future batch's, built inside eviction, nests under ``plan.evict``) and
+    ``plan.evict`` around the per-table slot allocation: the eligibility
+    sweep, future holds and victim selection (in the steady state every
+    call evicts; a call that only takes fresh slots records a short span).
     """
 
     def __init__(
@@ -231,6 +240,7 @@ class Planner:
         row_offsets: Optional[Sequence[int]] = None,
         slot_ranges: Optional[Sequence[Tuple[int, int]]] = None,
         memoize: bool = True,
+        tracer=None,
     ):
         if policy not in ("lru", "random", "lfu"):
             raise ValueError(f"unknown replacement policy {policy!r}")
@@ -246,6 +256,7 @@ class Planner:
         self.future_window = int(future_window)
         self.policy = policy
         self.memoize = bool(memoize)
+        self._tracer = tracer
         self._rng = np.random.default_rng(seed)
 
         # per-table partition of the row space and the slot space
@@ -289,6 +300,7 @@ class Planner:
         # zero-redundancy machinery: digest cache + preallocated scratch
         self._hitmap_version = 0
         self._digests = PinnedCache(4 * (self.future_window + 2))
+        self._build = self._build_digest  # bound once, not per lookup
         self._eligible_buf = np.empty(self.num_slots, dtype=bool)
         self._occupied_buf = np.empty(self.num_slots, dtype=bool)
 
@@ -341,15 +353,22 @@ class Planner:
                 f"planner has {self.num_tables} tables"
             )
 
+    def _span(self, name: str):
+        t = self._tracer
+        return NULL_SPAN if t is None else t.span(name, "plan")
+
+    def _unique(self, flat: np.ndarray) -> np.ndarray:
+        with self._span("plan.unique"):
+            return np.unique(flat)
+
     # -- plan digests --------------------------------------------------------
-    @staticmethod
-    def _build_digest(ids) -> _BatchDigest:
+    def _build_digest(self, ids) -> _BatchDigest:
         flat = np.asarray(ids, dtype=np.int32).ravel()
-        return _BatchDigest(flat, np.unique(flat))
+        return _BatchDigest(flat, self._unique(flat))
 
     def _digest(self, ids) -> _BatchDigest:
         """Digest of one batch object, memoized on array identity."""
-        return self._digests.get(ids, self._build_digest)
+        return self._digests.get(ids, self._build)
 
     def _probe(self, d: _BatchDigest) -> np.ndarray:
         """HitMap lookup of a digest's uniques, reused while the HitMap is
@@ -372,7 +391,7 @@ class Planner:
             slots_u = self._probe(d)
         else:
             flat = np.asarray(ids, dtype=np.int32).ravel()
-            uniq = np.unique(flat)
+            uniq = self._unique(flat)
             slots_u = self.hitmap[uniq]
 
         # Step B (Algorithm 1): advance the hold shift register by one cycle.
@@ -413,7 +432,7 @@ class Planner:
                         fslots = self._probe(self._digest(fb))
                     else:
                         fslots = self.hitmap[
-                            np.unique(np.asarray(fb, np.int32).ravel())
+                            self._unique(np.asarray(fb, np.int32).ravel())
                         ]
                     fslots = fslots[fslots >= 0]
                     eligible[fslots] = False  # future holds (RAW-4)
@@ -427,43 +446,44 @@ class Planner:
         seg = np.searchsorted(miss_ids, self.row_offsets)
         fill_parts: List[np.ndarray] = []
         victim_parts: List[np.ndarray] = []
-        for t in range(self.num_tables):
-            n_miss_t = int(seg[t + 1] - seg[t])
-            if n_miss_t == 0:
-                continue
-            lo, hi = self.slot_ranges[t]
-            n_fresh = min(n_miss_t, hi - int(self._free_ptrs[t]))
-            fresh = np.arange(
-                self._free_ptrs[t], self._free_ptrs[t] + n_fresh, dtype=np.int32
-            )
-            self._free_ptrs[t] += n_fresh
-            n_evict = n_miss_t - n_fresh
-            if n_evict > 0:
-                cand = np.flatnonzero(get_eligible()[lo:hi]).astype(np.int32) + lo
-                if cand.size < n_evict:
-                    raise RuntimeError(
-                        f"scratchpad too small: need {n_evict} victims, "
-                        f"only {cand.size} evictable (table {t}: "
-                        f"slots={hi - lo} of {self.num_slots}, "
-                        f"window={self.past_window}+1+{self.future_window}); "
-                        "size the Storage array for the worst-case window "
-                        "working set (paper §VI-D)."
-                    )
-                if self.policy == "lru":
-                    victims_t = _select_victims(
-                        self.last_use[cand], cand, n_evict
-                    )
-                elif self.policy == "lfu":
-                    victims_t = _select_victims(
-                        self.use_count[cand], cand, n_evict
-                    )
-                else:  # random
-                    order = self._rng.choice(cand.size, size=n_evict, replace=False)
-                    victims_t = cand[order]
-                victim_parts.append(victims_t)
-                fill_parts.append(np.concatenate([fresh, victims_t]))
-            else:
-                fill_parts.append(fresh)
+        with self._span("plan.evict"):
+            for t in range(self.num_tables):
+                n_miss_t = int(seg[t + 1] - seg[t])
+                if n_miss_t == 0:
+                    continue
+                lo, hi = self.slot_ranges[t]
+                n_fresh = min(n_miss_t, hi - int(self._free_ptrs[t]))
+                fresh = np.arange(
+                    self._free_ptrs[t], self._free_ptrs[t] + n_fresh, dtype=np.int32
+                )
+                self._free_ptrs[t] += n_fresh
+                n_evict = n_miss_t - n_fresh
+                if n_evict > 0:
+                    cand = np.flatnonzero(get_eligible()[lo:hi]).astype(np.int32) + lo
+                    if cand.size < n_evict:
+                        raise RuntimeError(
+                            f"scratchpad too small: need {n_evict} victims, "
+                            f"only {cand.size} evictable (table {t}: "
+                            f"slots={hi - lo} of {self.num_slots}, "
+                            f"window={self.past_window}+1+{self.future_window}); "
+                            "size the Storage array for the worst-case window "
+                            "working set (paper §VI-D)."
+                        )
+                    if self.policy == "lru":
+                        victims_t = _select_victims(
+                            self.last_use[cand], cand, n_evict
+                        )
+                    elif self.policy == "lfu":
+                        victims_t = _select_victims(
+                            self.use_count[cand], cand, n_evict
+                        )
+                    else:  # random
+                        order = self._rng.choice(cand.size, size=n_evict, replace=False)
+                        victims_t = cand[order]
+                    victim_parts.append(victims_t)
+                    fill_parts.append(np.concatenate([fresh, victims_t]))
+                else:
+                    fill_parts.append(fresh)
         victims = (
             np.concatenate(victim_parts)
             if victim_parts

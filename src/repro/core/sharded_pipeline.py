@@ -43,7 +43,6 @@ class ShardedScratchPipe:
         policy: str = "lru",
         boundaries: Optional[Sequence[int]] = None,
         executor: str = "sync",
-        record_stage_times: bool = False,
         planner: str = "host",
         pad_buckets: Optional[Sequence[int]] = None,
         kernel: str = "xla",
@@ -117,7 +116,6 @@ class ShardedScratchPipe:
                     future_window=future_window,
                     policy=policy,
                     executor=executor,
-                    record_stage_times=record_stage_times,
                     # planner="device": one device-resident PlanState per
                     # shard manager — per-shard id streams are variable
                     # length, which the device planner absorbs via its

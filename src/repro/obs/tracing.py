@@ -1,12 +1,37 @@
 """Span-based tracer with thread-correct wall-clock attribution.
 
 The paper's claims are per-stage overlap claims, so the tracer must answer
-"which *thread* spent how long in which *stage*" — exactly what
-``StepStats.stage_times`` (main-thread seconds only) cannot. Spans are
-recorded on whichever thread opens them: the overlapped executor's host
-worker, the d2h worker, the serving front-end, and the replay prefetcher
-each get their own event buffer, so a pool-submitted gather shows up on
+"which *thread* spent how long in which *stage*". Spans are recorded on
+whichever thread opens them: the overlapped executor's host worker, the
+d2h worker, the serving front-end, and the replay prefetcher each get
+their own event buffer, so a pool-submitted gather shows up on
 ``scratchpipe-host``, not on the main thread that enqueued it.
+
+``ScratchPipe``'s spans, nested as they run (main thread unless named)::
+
+    cycle                     one iteration of ScratchPipe.run
+      input                   next() on the stream + the look-ahead peek
+      plan                    [Plan]
+        plan.evict            eligibility sweep, future holds, victims
+          plan.unique         np.unique of a future batch's digest
+        plan.unique           np.unique of a batch digest
+        plan.materialize      device planner (d2h thread if overlapped)
+      collect                 [Collect]
+        collect.gather        host gather (host worker if overlapped)
+      exchange                [Exchange] (+ the gather wait if overlapped)
+        exchange.pad          copy of the missed rows into their bucket
+        exchange.h2d          jax.device_put of the padded rows
+        exchange.d2h          victim read sync + slice (d2h thread if
+                              overlapped)
+      insert_host             [Insert], host half
+        insert.writeback      host scatter (host worker if overlapped)
+      insert_fill             [Insert], device half
+      train                   [Train]
+      retire                  release of the retired batch's arrays
+
+With a metrics registry installed the runtime also counts what crosses the
+host-device link, padding included: ``cache.h2d_rows``/``cache.h2d_bytes``
+and ``cache.d2h_rows``/``cache.d2h_bytes``.
 
 Cost model:
 
@@ -186,9 +211,8 @@ class Tracer:
 
     def totals(self) -> Dict[Tuple[str, str], float]:
         """Aggregate span seconds keyed by (thread_name, span_name) —
-        thread-correct per-stage wall time, the replacement for the
-        deprecated main-thread-only ``StepStats.stage_times``. Nested spans
-        each accrue their own full duration."""
+        thread-correct per-stage wall time. Nested spans each accrue their
+        own full duration."""
         out: Dict[Tuple[str, str], float] = {}
         for _tid, tname, buf in self._snapshot_threads():
             stack: List[Tuple[str, float]] = []

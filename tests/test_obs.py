@@ -15,18 +15,27 @@
   O6  serving: serve.* counters match replay results (requests, latency
       histogram count, emergency accounting vs StepStats.aux).
   O7  the validators actually reject corrupt artifacts.
+  O8  stage spans nest as documented (cycle > stages > sub-spans), and the
+      link counters count the padded rows and bytes that cross h2d / d2h.
 """
+import importlib.util
 import json
+import pathlib
 import threading
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.configs.base import DLRMConfig
+from repro.core.dlrm_runtime import DLRMTrainer
 from repro.core.host_table import HostEmbeddingTable
 from repro.core.pipeline import ScratchPipe
+from repro.core.plan import pad_len
+from repro.core.runtime import make_runtime
 from repro.core.serving_cache import NoCacheServer, ReadOnlyCacheServer
 from repro.core.table_group import TableGroup
 from repro.data.lookahead import LookaheadStream
@@ -202,13 +211,14 @@ def test_install_resolve_precedence():
 # ---------------------------------------------------------------------------
 # O4: bit parity under tracing
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("executor", ["overlapped", "sync"])
 @pytest.mark.parametrize("scenario", ["drift", "flash_crowd"])
-def test_traced_overlapped_parity(scenario):
+def test_traced_overlapped_parity(scenario, executor):
     group, batches = group_batches(scenario)
     ref, ref_stats, _ = run_pipe(batches, group, executor="sync")
     tr, m = obs.Tracer(), obs.MetricsRegistry()
     got, got_stats, _ = run_pipe(
-        batches, group, executor="overlapped", tracer=tr, metrics=m
+        batches, group, executor=executor, tracer=tr, metrics=m
     )
     np.testing.assert_array_equal(ref, got)
     assert [s.n_hits for s in ref_stats] == [s.n_hits for s in got_stats]
@@ -324,3 +334,124 @@ def test_validators_reject_bad_artifacts(tmp_path):
     bad_metrics = tmp_path / "bad.jsonl"
     bad_metrics.write_text('{"kind": "counter", "name": "x"}\n')
     assert validate_metrics_jsonl(str(bad_metrics)) != []
+
+
+# ---------------------------------------------------------------------------
+# O8: stage span nesting and link counters
+# ---------------------------------------------------------------------------
+def span_parents(tracer):
+    """(thread name, span name, enclosing span name or None) of every span,
+    from the exported events."""
+    events = tracer.events()
+    names = {e["tid"]: e["args"]["name"] for e in events if e["ph"] == "M"}
+    out, stacks = [], {}
+    for e in events:
+        st = stacks.setdefault(e["tid"], [])
+        if e["ph"] == "B":
+            out.append((names[e["tid"]], e["name"], st[-1] if st else None))
+            st.append(e["name"])
+        elif e["ph"] == "E":
+            st.pop()
+    return out
+
+
+STAGES = ("input", "plan", "collect", "exchange", "insert_host",
+          "insert_fill", "train", "retire")
+
+
+@pytest.mark.parametrize("executor", ["sync", "overlapped"])
+def test_stage_span_nesting(executor):
+    group, batches = group_batches("flash_crowd")
+    tr = obs.Tracer()
+    _, stats, _ = run_pipe(batches, group, executor=executor, tracer=tr)
+    assert sum(s.n_evict for s in stats) > 0  # the eviction path ran
+    seen = {}
+    for thread, name, parent in span_parents(tr):
+        seen.setdefault(name, set()).add((thread, parent))
+    main = "MainThread"
+    assert seen["cycle"] == {(main, None)}
+    for name in STAGES:
+        assert seen[name] == {(main, "cycle")}, name
+    assert seen["exchange.pad"] == {(main, "exchange")}
+    assert seen["exchange.h2d"] == {(main, "exchange")}
+    assert seen["plan.evict"] == {(main, "plan")}
+    # a digest is built at [Plan] or, for a future batch, inside eviction
+    assert {p for _, p in seen["plan.unique"]} <= {"plan", "plan.evict"}
+    assert {t for t, _ in seen["plan.unique"]} == {main}
+    if executor == "sync":
+        assert seen["insert.writeback"] == {(main, "insert_host")}
+        assert seen["collect.gather"] == {(main, "collect")}
+        assert seen["exchange.d2h"] == {(main, "exchange")}
+    else:  # pool work lands on the worker that ran it, at its top level
+        assert seen["insert.writeback"] == {("scratchpipe-host_0", None)}
+        assert seen["collect.gather"] == {("scratchpipe-host_0", None)}
+        assert seen["exchange.d2h"] == {("scratchpipe-d2h_0", None)}
+
+
+@pytest.mark.parametrize("executor", ["sync", "overlapped"])
+def test_link_counters_count_padded_rows(executor):
+    group, batches = group_batches("drift")
+    m = obs.MetricsRegistry()
+    _, stats, _ = run_pipe(batches, group, executor=executor, metrics=m)
+    lbl = {"runtime": "scratchpipe"}
+    h2d = sum(pad_len(s.n_miss) for s in stats if s.n_miss)
+    d2h = sum(pad_len(s.n_evict) for s in stats if s.n_evict)
+    assert d2h > 0
+    row = DIM * 4  # fp32 rows
+    got = {k: m.counter(f"cache.{k}", **lbl).value
+           for k in ("h2d_rows", "h2d_bytes", "d2h_rows", "d2h_bytes")}
+    assert got == {"h2d_rows": h2d, "h2d_bytes": h2d * row,
+                   "d2h_rows": d2h, "d2h_bytes": d2h * row}
+    # the benchmark's padding share, read from these counters, agrees with
+    # the share computed here from the step stats
+    real = sum(s.n_miss + s.n_evict for s in stats)
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks"
+            / "chip" / "metrics" / "pad_share_pct.py")
+    spec = importlib.util.spec_from_file_location("pad_share_pct", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    class Ctx:
+        counters = {r["name"]: r["value"] for r in m.snapshot()
+                    if r["kind"] == "counter" and "table" not in r["labels"]}
+
+    assert reader.read(Ctx) == pytest.approx(100 * (1 - real / (h2d + d2h)))
+
+
+def test_traced_int8_parity_and_link_bytes():
+    """Tracing keeps the quantizing gather (a traced int8 run equals the
+    untraced one), and the link counters add up both parts of an int8
+    row block: the payload and its scale column."""
+    group, batches = group_batches("drift", steps=12)
+    cfg = DLRMConfig(
+        name="dlrm-obs-int8", table_rows=(400, 400), embed_dim=DIM,
+        lookups_per_table=3, batch_size=4, bottom_mlp=(16, DIM),
+        top_mlp=(16, 1), precision="int8", rounding="nearest",
+    )
+    rng = np.random.default_rng(3)
+    items = [(b, {"dense": rng.standard_normal((4, 13)).astype(np.float32),
+                  "label": rng.integers(0, 2, 4).astype(np.float32)})
+             for b in batches]
+
+    def run(**kw):
+        host = HostEmbeddingTable(group.total_rows, DIM, seed=1)
+        trainer = DLRMTrainer(cfg, jax.random.key(0), lr=0.05)
+        pipe = make_runtime("scratchpipe", host, trainer.train_fn,
+                            num_slots=24, precision="int8", **kw)
+        stream = LookaheadStream(iter(items))
+        stats = pipe.run(stream, lookahead_fn=stream.peek_ids)
+        pipe.flush_to_host()
+        return host.data.copy(), [float(s.aux["loss"]) for s in stats], stats
+
+    ref, ref_loss, _ = run()
+    m = obs.MetricsRegistry()
+    got, got_loss, stats = run(tracer=obs.Tracer(), metrics=m)
+    np.testing.assert_array_equal(ref, got)
+    assert ref_loss == got_loss
+    lbl = {"runtime": "scratchpipe"}
+    h2d = sum(pad_len(s.n_miss) for s in stats if s.n_miss)
+    d2h = sum(pad_len(s.n_evict) for s in stats if s.n_evict)
+    assert d2h > 0
+    row = DIM + 4  # int8 payload + one fp32 scale
+    assert m.counter("cache.h2d_bytes", **lbl).value == h2d * row
+    assert m.counter("cache.d2h_bytes", **lbl).value == d2h * row
