@@ -161,12 +161,25 @@ class HostEmbeddingTable:
         self.reguard(ids)
 
     # -- access path --------------------------------------------------------
-    def gather(self, ids: np.ndarray) -> np.ndarray:
-        """[Collect]: read missed rows from the capacity tier."""
+    def gather(self, ids: np.ndarray, out: Optional[np.ndarray] = None) -> np.ndarray:
+        """[Collect]: read missed rows from the capacity tier. With ``out``
+        (``(ids.size, dim)``, the table's dtype) the rows are written there
+        and ``out`` is returned, so a caller can gather into a buffer it
+        reuses instead of a fresh array per call."""
         if self._sums is not None:
             self.verify(ids)
         self.traffic.read += ids.size * self.row_bytes
-        return self.data[ids]
+        if out is None:
+            return self.data[ids]
+        # np.take buffers the whole output when given ``out=`` under its
+        # default mode="raise"; "clip" writes in place, so the bounds check
+        # that fancy indexing makes is made here
+        if ids.size and (ids.min() < 0 or ids.max() >= self.rows):
+            raise IndexError(
+                f"row ids out of range [0, {self.rows}): "
+                f"min {int(ids.min())}, max {int(ids.max())}"
+            )
+        return np.take(self.data, ids, axis=0, out=out, mode="clip")
 
     def scatter(self, ids: np.ndarray, values: np.ndarray) -> None:
         """[Insert]: write evicted (dirty, trained) rows back."""

@@ -74,6 +74,15 @@ small rows), evictions dequantize on write-back, and the pcie/hbm counters
 track the replica row size (== the fp32 size at fp32, so the default path's
 counters are bitwise unchanged). Pair with a trainer built with the same
 ``precision=`` so [Train] uses the dequantizing gather.
+
+h2d staging (fp32 replicas): [Collect] gathers the missed rows straight into
+the head of a reused host block already padded to the operand's bucket, and
+[Exchange] hands that whole block to ``jax.device_put`` — no fresh gather
+output, no pad copy. The rows past the real ones are stale rows of an
+earlier batch; their fill slots are the ``num_slots`` sentinel, which every
+fill discards. A block is written again only after the transfer last put
+from it has finished (``_StagingRing.wait``). Quantized replicas keep the
+gather -> quantize -> ``pad_rows`` path.
 """
 from __future__ import annotations
 
@@ -127,6 +136,7 @@ class _InFlight:
     plan: Optional[PlanResult] = None
     host_rows: Optional[np.ndarray] = None  # [Collect] host->staging
     host_rows_f: Optional[SupervisedOp] = None  # overlapped: pending gather
+    staging: Optional["_StagingBlock"] = None  # fp32: block host_rows heads
     evicted_dev: Optional[jax.Array] = None  # [Collect] device victim read
     fetched_dev: Optional[jax.Array] = None  # [Exchange] h2d
     evicted_host: Optional[np.ndarray] = None  # [Exchange] d2h
@@ -165,6 +175,78 @@ def _link_size(block) -> Tuple[int, int]:
     link: one array, or an int8 scratchpad's (payload, scale) pair."""
     parts = block if isinstance(block, tuple) else (block,)
     return int(parts[0].shape[0]), sum(int(a.nbytes) for a in parts)
+
+
+#: host staging blocks per runtime. A block is written at [Collect], put at
+#: [Exchange] the next cycle and filled from the cycle after, so with three
+#: its transfer has a whole cycle to finish before [Collect] writes it again.
+STAGING_RING = 3
+
+
+class _StagingBlock:
+    """One reused host block of padded missed rows, and the device array
+    last put from it, which has to be ready before the block is rewritten."""
+
+    __slots__ = ("buf", "last_put")
+
+    def __init__(self, buf: np.ndarray):
+        self.buf = buf
+        self.last_put: Optional[jax.Array] = None
+
+
+class _StagingRing:
+    """[Collect]'s gather target and [Exchange]'s h2d source for plain
+    (unquantized) rows: :data:`STAGING_RING` blocks of one padded length,
+    taken in ring order and allocated, and touched, once. A change of the
+    padded length (bucket) drops them and allocates anew.
+
+    ``counters`` holds the registry's ``staging_{allocs,reuses,waits}``
+    counters when one is installed. ``put_copies``: the CPU backend's ``device_put``
+    aliases a numpy array instead of copying it, so a reused block would
+    rewrite a live ``jax.Array``; there the block is put as a copy."""
+
+    def __init__(self, dim: int, dtype, buckets, *, put_copies: bool):
+        self._dim = dim
+        self._dtype = np.dtype(dtype)
+        self._buckets = buckets
+        self.put_copies = put_copies
+        self.counters: Optional[Dict[str, Any]] = None
+        self._len = 0
+        self._blocks: List[_StagingBlock] = []
+        self._next = 0
+
+    def _count(self, name: str) -> None:
+        if self.counters is not None:
+            self.counters[name].inc()
+
+    def take(self, n: int) -> _StagingBlock:
+        """The next block for ``n`` rows, in ring order (main thread)."""
+        p = pad_len(n, self._buckets)
+        if p != self._len:
+            self._len, self._blocks, self._next = p, [], 0
+        i = self._next
+        self._next = (i + 1) % STAGING_RING
+        if i < len(self._blocks):
+            self._count("staging_reuses")
+            return self._blocks[i]
+        buf = np.empty((p, self._dim), self._dtype)
+        buf.fill(0)  # fault its pages in now, not in a step's gather
+        block = _StagingBlock(buf)
+        self._blocks.append(block)
+        self._count("staging_allocs")
+        return block
+
+    def wait(self, block: _StagingBlock) -> None:
+        """Return once the transfer last put from ``block`` has finished, so
+        the block may be written (the host worker under overlapped)."""
+        dev, block.last_put = block.last_put, None
+        if dev is not None and not dev.is_ready():
+            self._count("staging_waits")
+            dev.block_until_ready()
+
+    def source(self, block: _StagingBlock) -> np.ndarray:
+        """What [Exchange] hands to ``device_put``: the whole padded block."""
+        return block.buf.copy() if self.put_copies else block.buf
 
 
 class ScratchPipe:
@@ -338,6 +420,14 @@ class ScratchPipe:
             self._d2h_slice_fn = self._tracer.wrap(
                 "exchange.d2h", _d2h_slice, cat="d2h"
             )
+        self._ring: Optional[_StagingRing] = None
+        if self.precision == "fp32":  # quantized rows keep the pad copy
+            self._ring = _StagingRing(
+                host_table.dim,
+                host_table.data.dtype,
+                self.pad_buckets,
+                put_copies=next(iter(self.storage.devices())).platform == "cpu",
+            )
         self._mc = None
         if self._metrics is not None:
             self._setup_metrics(dict(obs_labels or {}))
@@ -366,6 +456,12 @@ class ScratchPipe:
                       # rows and bytes handed to the link, padding included
                       "h2d_rows", "h2d_bytes", "d2h_rows", "d2h_bytes")
         }
+        if self._ring is not None:
+            # h2d staging blocks: allocated, reused, and reuses that found
+            # the block's last transfer not yet finished
+            for k in ("staging_allocs", "staging_reuses", "staging_waits"):
+                self._mc[k] = m.counter(f"cache.{k}", **labels)
+            self._ring.counters = self._mc
         self._tbl_counters = None
         if self.table_group is not None:
             self._tbl_counters = [
@@ -589,16 +685,26 @@ class ScratchPipe:
                 # ids back on the d2h worker so the sync overlaps [Train]
                 entry.plan.start_materialize(self._d2h_pool, tracer=self._tracer)
 
+    def _collect_into(self, ids: np.ndarray, block: _StagingBlock):
+        """[Collect] into a staging block: wait out the block's last
+        transfer, then gather the rows over its head. Pure given the host
+        table, so a supervised inline recompute rewrites the same bytes."""
+        self._ring.wait(block)
+        return self._gather_fn(ids, out=block.buf[: ids.size])
+
     def _stage_collect(self, entry: _InFlight):
         with self._span("collect"):
             p = entry.plan
             if p.miss_ids.size:
+                fn, args = self._gather_fn, (p.miss_ids,)
+                if self._ring is not None:
+                    # taken here, in ring order, even when a worker gathers
+                    entry.staging = self._ring.take(p.miss_ids.size)
+                    fn, args = self._collect_into, (p.miss_ids, entry.staging)
                 if self._host_pool is not None:
-                    entry.host_rows_f = self._submit_host(
-                        self._gather_fn, p.miss_ids
-                    )
+                    entry.host_rows_f = self._submit_host(fn, *args)
                 else:
-                    entry.host_rows = self._gather_fn(p.miss_ids)  # host read
+                    entry.host_rows = fn(*args)  # host read
             if p.evict_slots.size:
                 # pad victim reads to the pow-2 bucket (slot 0 is always safe
                 # to read); the d2h side slices the real rows back out
@@ -617,13 +723,18 @@ class ScratchPipe:
                     if entry.host_rows_f is not None
                     else entry.host_rows
                 )
+                block = entry.staging
                 with self._span("exchange.pad", cat="host"):
-                    if isinstance(rows, tuple):  # int8: (payload, scale) pair
+                    if block is not None:  # gathered into a padded block
+                        rows = self._ring.source(block)
+                    elif isinstance(rows, tuple):  # int8: (payload, scale)
                         rows = tuple(pad_rows(r, self.pad_buckets) for r in rows)
-                    else:
+                    else:  # quantized, or restored from a checkpoint
                         rows = pad_rows(rows, self.pad_buckets)
                 with self._span("exchange.h2d", cat="h2d"):
                     entry.fetched_dev = jax.device_put(rows)
+                if block is not None:
+                    block.last_put = entry.fetched_dev
                 if mc is not None:
                     n, nbytes = _link_size(rows)
                     mc["h2d_rows"].inc(n)
@@ -930,6 +1041,9 @@ class ScratchPipe:
             host_rows = e.host_rows
             if e.host_rows_f is not None:
                 host_rows = self._op_result(e.host_rows_f)
+            if e.staging is not None and host_rows is not None:
+                # the real rows only, out of a block a later [Collect] reuses
+                host_rows = np.array(host_rows)
             evicted_host = e.evicted_host
             if e.evicted_host_f is not None:
                 evicted_host = self._d2h_value(e.evicted_host_f)

@@ -17,9 +17,11 @@ their own event buffer, so a pool-submitted gather shows up on
         plan.unique           np.unique of a batch digest
         plan.materialize      device planner (d2h thread if overlapped)
       collect                 [Collect]
-        collect.gather        host gather (host worker if overlapped)
+        collect.gather        host gather (host worker if overlapped),
+                              into an h2d staging block at fp32
       exchange                [Exchange] (+ the gather wait if overlapped)
-        exchange.pad          copy of the missed rows into their bucket
+        exchange.pad          the staging block taken as it is (fp32), or
+                              the copy of quantized rows into their bucket
         exchange.h2d          jax.device_put of the padded rows
         exchange.d2h          victim read sync + slice (d2h thread if
                               overlapped)
@@ -31,7 +33,9 @@ their own event buffer, so a pool-submitted gather shows up on
 
 With a metrics registry installed the runtime also counts what crosses the
 host-device link, padding included: ``cache.h2d_rows``/``cache.h2d_bytes``
-and ``cache.d2h_rows``/``cache.d2h_bytes``.
+and ``cache.d2h_rows``/``cache.d2h_bytes``; and, at fp32, the h2d staging
+blocks: ``cache.staging_allocs``, ``cache.staging_reuses`` and
+``cache.staging_waits`` (reuses that waited for the block's last put).
 
 Cost model:
 

@@ -19,6 +19,7 @@ import pytest
 
 import repro.checkpoint.manager as ckpt_manager
 from repro.checkpoint import CheckpointManager
+from repro.checkpoint.pack import unpack_blob
 from repro.configs.base import DLRMConfig
 from repro.core.dlrm_runtime import DLRMTrainer
 from repro.core.host_table import HostEmbeddingTable
@@ -228,6 +229,64 @@ def test_sharded_midwindow_kill_resume_parity(tmp_path):
     np.testing.assert_array_equal(_losses(stats_resumed), _losses(stats_a))
     np.testing.assert_array_equal(host_c.data, host_a.data)
     _assert_state_equal(pipe_c.state_arrays(), final_a)
+
+
+@pytest.mark.parametrize("executor", ["sync", "overlapped"])
+def test_checkpoint_of_staged_rows_survives_ring_reuse(traces, executor):
+    """A checkpoint taken while a batch's rows sit in an h2d staging block
+    (after [Collect], before [Exchange]) holds the real rows themselves:
+    the run that took it goes on and rewrites every block, and a resume
+    from the checkpoint is still bit-identical to the uninterrupted run."""
+    reader = traces["drift"]
+    host_a, _, pipe_a = fresh(executor, "host", "fp32")
+    sa = TraceReplayStream(reader, stop=STEPS)
+    stats_a = pipe_a.run(sa, lookahead_fn=sa.peek_ids)
+    pipe_a.flush_to_host()
+    final_a = pipe_a.state_arrays()
+    pipe_a.close()
+
+    host_b, tr_b, pipe_b = fresh(executor, "host", "fp32")
+    sb = TraceReplayStream(reader, stop=STEPS)
+    it = iter(sb)
+    for _ in range(KILL_AT):
+        pipe_b.run_one_cycle(*next(it), sb.peek_ids)
+    staged = [e for e in pipe_b._window if e.stage == 2]
+    assert len(staged) == 1 and staged[0].staging is not None
+    n = staged[0].plan.miss_ids.size
+    block = staged[0].staging.buf
+    head = block[:n].copy()
+    # the host table and scratchpad are live arrays: copy them as a save
+    # would; the window is left exactly as captured
+    saved = {k: (v if k == "window" else np.array(v))
+             for k, v in pipe_b.state_arrays().items()}
+    (entry,) = [d for d in unpack_blob(saved["window"]) if d["stage"] == 2]
+    assert entry["host_rows"].shape == (n, CFG.embed_dim)  # real rows only
+    mlps = jax.tree.map(np.array, tr_b.mlps)
+    trainer_step = int(tr_b._step)
+    stats_before = list(pipe_b.stats)
+    for ids, batch in it:  # the crashed run goes on and reuses every block
+        pipe_b.run_one_cycle(ids, batch, sb.peek_ids)
+    pipe_b.close()
+    assert not np.array_equal(block[:n], head), "the block was not rewritten"
+
+    host_c, tr_c, pipe_c = fresh(executor, "host", "fp32")
+    tr_c.mlps = jax.tree.map(jax.numpy.asarray, mlps)
+    tr_c._step = trainer_step
+    pipe_c.load_state_arrays(saved)
+    sc = TraceReplayStream(reader, start=KILL_AT, stop=STEPS)
+    for ids, batch in iter(sc):
+        pipe_c.run_one_cycle(ids, batch, sc.peek_ids)
+    while pipe_c._window:
+        pipe_c.drain_one_cycle()
+    pipe_c.flush_to_host()
+    final_c = pipe_c.state_arrays()
+    stats_resumed = stats_before + list(pipe_c.stats)
+    pipe_c.close()
+
+    np.testing.assert_array_equal(_losses(stats_resumed), _losses(stats_a))
+    assert _plan_seq(stats_resumed) == _plan_seq(stats_a)
+    np.testing.assert_array_equal(host_c.data, host_a.data)
+    _assert_state_equal(final_c, final_a)
 
 
 # --------------------------------------------------------------------------- #
