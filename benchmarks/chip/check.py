@@ -3,9 +3,11 @@
 Each number compares the program's states with the plain reference's, leaf
 by leaf, by the gap between the two norms (not the norm of the difference),
 as a share of the larger of the reference leaf's norm and the median
-leaf's. A leaf is an MLP weight or bias, or one embedding table (the rows
-read at that point). Leaves whose reference value is under a thousandth of
-the median leaf's are left out: they move by rounding alone.
+leaf's. A leaf is one array of the dense parameters (an MLP weight or
+bias, or any other part of the model's tree), or one embedding table (the
+rows read at that point; a table none of whose rows is read there has no
+leaf). Leaves whose reference value is under a thousandth of the median
+leaf's are left out: they move by rounding alone.
 
 The states read are given by :func:`points`: the first steps from the
 seed, and the first steps after warm-up, when the scratchpad is full and
@@ -91,22 +93,50 @@ def reads(pts: dict) -> dict:
     return out
 
 
-def leaves(mlps: dict, rows: np.ndarray, table_of_row: np.ndarray, tables: int):
-    """Flatten one model state: the MLP leaves in a fixed order, then one
-    leaf per embedding table."""
-    out = []
-    for part in ("bottom", "top"):
-        for i, lyr in enumerate(mlps[part]):
-            out.append((f"{part}{i}.w", np.asarray(lyr["w"], np.float64)))
-            out.append((f"{part}{i}.b", np.asarray(lyr["b"], np.float64)))
+def _paths(tree, name=""):
+    """(name, array) of each array in a tree of dicts and lists: dict keys
+    in sorted order, joined by ``.``, list positions appended to the key
+    (``bottom0.w``)."""
+    if isinstance(tree, dict):
+        for k in sorted(tree):
+            yield from _paths(tree[k], f"{name}.{k}" if name else str(k))
+    elif isinstance(tree, (list, tuple)):
+        for i, x in enumerate(tree):
+            yield from _paths(x, f"{name}{i}")
+    else:
+        yield name, tree
+
+
+def _tree_map(fn, *trees):
+    """``fn`` over the arrays of trees of one structure, leaf by leaf."""
+    a = trees[0]
+    if isinstance(a, dict):
+        return {k: _tree_map(fn, *(t[k] for t in trees)) for k in a}
+    if isinstance(a, (list, tuple)):
+        return [_tree_map(fn, *xs) for xs in zip(*trees)]
+    return fn(*trees)
+
+
+def leaves(dense, rows: np.ndarray, table_of_row: np.ndarray, tables: int):
+    """Flatten one model state: the dense parameters (any tree of dicts and
+    lists, such as the bottom and top MLPs) leaf by leaf in a fixed order,
+    named by path, then one leaf per embedding table that has rows here
+    (every row of a small table may be read again later, so that none is
+    among the write-back rows)."""
+    out = [(n, np.asarray(a, np.float64)) for n, a in _paths(dense)]
     for t in range(tables):
-        out.append((f"table{t}", np.asarray(rows[table_of_row == t], np.float64)))
+        mine = table_of_row == t
+        if mine.any():
+            out.append((f"table{t}", np.asarray(rows[mine], np.float64)))
     return out
 
 
 def worst_leaf_gap(prog, ref):
     """Worst gap between per-leaf norms. ``prog``/``ref``: [(name, array)]
-    in the same order. Returns (gap, leaf name, leaves compared)."""
+    in the same order. Returns (gap, leaf name, leaves compared); the gap
+    is not a number where there is no leaf."""
+    if not ref:
+        return float("nan"), "no leaf", 0
     nref = np.array([np.linalg.norm(a) for _, a in ref])
     nprog = np.array([np.linalg.norm(a) for _, a in prog])
     med = float(np.median(nref))
@@ -128,28 +158,27 @@ def numbers(cfg, lr, pts, p, r, tbl):
     """The compared numbers for one run, and where each was worst.
 
     ``p`` (program) and ``r`` (reference or control) each hold ``loss``
-    ({step: loss}), ``mlps`` ({steps done: MLP tree}, 0 the start) and
-    ``rows`` ({(steps done, name): rows}; state 0 is the initial rows).
-    Either may hold ``rows[("end", "writeback")]``: ``p`` the rows touched
-    in the checked steps and never again, read from the host tier after
-    the run's final flush, ``r`` the same rows after the last checked step;
+    ({step: loss}), ``mlps`` ({steps done: tree of the dense parameters},
+    0 the start) and ``rows`` ({(steps done, name): rows}; state 0 is the
+    initial rows). Either may hold ``rows[("end", "writeback")]``: ``p``
+    the rows touched in the checked steps and never again, read from the
+    host tier after the run's final flush, ``r`` the same rows after the
+    last checked step;
     ``rows[(0, "writeback")]`` are their initial rows. ``tbl``: {name: the
     table of each row}.
     """
     T = cfg["num_tables"]
 
     def sub(a, b, k=1.0):  # (a - b) * k, leaf by leaf
-        return {part: [{n: (np.asarray(x[n], np.float64)
-                            - np.asarray(y[n], np.float64)) * k
-                        for n in ("w", "b")} for x, y in zip(a[part], b[part])]
-                for part in ("bottom", "top")}
+        return _tree_map(lambda x, y: (np.asarray(x, np.float64)
+                                       - np.asarray(y, np.float64)) * k, a, b)
 
     def grad(s, a, b, name):
         d = (s["rows"][(a, name)] - s["rows"][(b, name)]) / lr
         return leaves(sub(s["mlps"][a], s["mlps"][b], 1 / lr), d, tbl[name], T)
 
     def change(s, n, name, mlps):
-        m = sub(s["mlps"][n], s["mlps"][0]) if mlps else {"bottom": [], "top": []}
+        m = sub(s["mlps"][n], s["mlps"][0]) if mlps else {}
         return leaves(m, s["rows"][(n, name)] - s["rows"][(0, name)], tbl[name], T)
 
     steps = pts["losses"]

@@ -64,12 +64,12 @@ def readings(cfg: dict, mix: dict, seed: int, variants=VARIANTS):
     rows0 = host_tier.rows_of(seed, u_all, cfg["embed_dim"])
     seed32 = int(np.random.default_rng(host_tier.seed_key(seed)).integers(2**31))
     key = jax.random.key(seed32)
-    R = cfg["rows_per_table"]
     pos = {nm: np.searchsorted(u_all, ids) for nm, ids in ids_of.items()}
     want = check.reads(pts)
     keep = {n: {nm: pos[nm] for nm in names} for n, names in want.items()}
     keep.setdefault(C, {})["writeback"] = pos["writeback"]
-    tbl = {nm: ids // R for nm, ids in ids_of.items()}
+    offsets = traffic_gen.row_offsets(cfg)
+    tbl = {nm: traffic_gen.table_of(offsets, ids) for nm, ids in ids_of.items()}
 
     def side(**kw):
         s = ref.train(cfg, key, u_all, rows0, batches, cfg["lr"], keep, **kw)

@@ -12,22 +12,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import traffic_gen
+
 _take_rows = jax.jit(lambda storage, slots: jnp.take(storage, slots, axis=0))
 
 
 def build(cfg: dict, key, data: np.ndarray):
-    """(host table, trainer, runtime) for one configuration."""
+    """(host table, trainer, runtime) for one configuration, with every
+    padded operand shape warmed up (:func:`warm`). Tables of unequal rows
+    (``table_rows``) are fused by the program into one row space under one
+    slot pool; unequal ``pooling`` raises NotImplementedError."""
     from repro.configs.base import DLRMConfig
     from repro.core.dlrm_runtime import DLRMTrainer
     from repro.core.host_table import HostEmbeddingTable
     from repro.core.runtime import make_runtime
 
+    pool = traffic_gen.pooling(cfg)
+    if len(set(pool)) > 1:
+        raise NotImplementedError(
+            f"{cfg['name']}: pooling differs between tables ({pool}); the "
+            "program takes one lookups_per_table for every table, as one "
+            "(B, T, L) block of ids (ROADMAP Reach 2: ragged bags)")
+    shape = ({"table_rows": tuple(traffic_gen.table_rows(cfg))}
+             if "table_rows" in cfg else
+             {"rows_per_table": cfg["rows_per_table"]})
     dc = DLRMConfig(
         name=cfg["name"],
         num_tables=cfg["num_tables"],
-        rows_per_table=cfg["rows_per_table"],
+        **shape,
         embed_dim=cfg["embed_dim"],
-        lookups_per_table=cfg["lookups_per_table"],
+        lookups_per_table=pool[0],
         num_dense_features=cfg["num_dense_features"],
         bottom_mlp=tuple(cfg["bottom_mlp"]),
         top_mlp=tuple(cfg["top_mlp"]),
@@ -42,7 +56,39 @@ def build(cfg: dict, key, data: np.ndarray):
         num_slots=cfg["num_slots"],
         past_window=dc.past_window, future_window=dc.future_window,
     )
+    warm(pipe, cfg["batch_size"] * sum(pool))
     return host, trainer, pipe
+
+
+def pad_sizes(pipe, n_max: int) -> list:
+    """Every length to which the runtime pads a victim read or a fill of
+    1..``n_max`` rows."""
+    from repro.core.plan import pad_len
+
+    out, n = [], 1
+    while n <= n_max:
+        out.append(pad_len(n, pipe.pad_buckets))
+        n = out[-1] + 1
+    return out
+
+
+def warm(pipe, n_max: int) -> None:
+    """Compile, or load from the cache, [Collect]'s victim read and
+    [Insert]'s fill at every padded length of up to ``n_max`` rows, the ids
+    of one step. How many rows a step misses and evicts depends on the seed
+    (the first step that evicts takes anything from one victim to a step's
+    misses), so a seed can reach a length that the seeds before it did not.
+    Its run would then compile in set-up, and a process that has compiled
+    runs the window faster (the host's heap; PERF.md). Each fill writes the
+    rows just read to the out-of-range sentinel slot: the scratchpad is left
+    as it was, and no host block of rows is made that would move the heap."""
+    from repro.core import scratchpad as sp
+
+    for p in pad_sizes(pipe, n_max):
+        rows = sp.read(pipe.storage, np.zeros(p, np.int32))
+        pipe.storage = sp.fill(pipe.storage, np.full(p, pipe.num_slots, np.int32),
+                               rows, kernel=pipe.kernel)
+    jax.block_until_ready(pipe.storage)
 
 
 def run(pipe, batches):

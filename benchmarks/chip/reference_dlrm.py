@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import traffic_gen
+
 
 def mlp_dims(cfg: dict):
     n = cfg["num_tables"] + 1
@@ -153,7 +155,7 @@ def train_step_cost(cfg: dict, n_unique: float, n_fill: float):
     FLOPs: the model's training FLOPs, the bag sums and the row updates."""
     row = cfg["embed_dim"] * 4
     b = cfg["batch_size"]
-    lookups = b * cfg["num_tables"] * cfg["lookups_per_table"]
+    lookups = b * sum(traffic_gen.pooling(cfg))
     bottom, top = mlp_dims(cfg)
     n_params = sum(a * c + c for a, c in zip(bottom[:-1], bottom[1:]))
     n_params += sum(a * c + c for a, c in zip(top[:-1], top[1:]))

@@ -84,6 +84,12 @@ def load_cell(name: str, root: pathlib.Path = ROOT, here: pathlib.Path = HERE):
     entry = next(c for c in bench["configs"] if c["name"] == w["config"])
     cfg = json.loads((root / entry["file"]).read_text())
     cfg["name"] = entry["name"]
+    for key in ("table_rows", "pooling"):  # one entry per table
+        if key in cfg:
+            n = len(cfg[key])
+            if cfg.setdefault("num_tables", n) != n:
+                raise SystemExit(f"{entry['file']}: {key} lists {n} tables, "
+                                 f"num_tables is {cfg['num_tables']}")
     mix = json.loads((here / "traffic" / f"{w['traffic']}.json").read_text())
 
     def mine(m):
@@ -271,8 +277,8 @@ def _run(args, w, cfg, mix, e2e, per_layer, producer, root, here, require_tpu):
         f"count={len(devs)}; host RSS {host_rss_gb():.1f} GB")
 
     # -- set-up ---------------------------------------------------------- #
-    R = cfg["rows_per_table"]
-    rows = cfg["num_tables"] * R
+    offsets = traffic_gen.row_offsets(cfg)
+    rows = int(offsets[-1])
     B = cfg["batch_size"]
     W = int(mix["warmup_steps"])
     pts = check.points(W)
@@ -445,7 +451,7 @@ def _run(args, w, cfg, mix, e2e, per_layer, producer, root, here, require_tpu):
     r["rows"][("end", "writeback")] = r["rows"].pop((C, "writeback"))
     for nm, ids in ids_of.items():
         p["rows"][(0, nm)] = r["rows"][(0, nm)] = rows0[pos[nm]]
-    tbl = {nm: ids // R for nm, ids in ids_of.items()}
+    tbl = {nm: traffic_gen.table_of(offsets, ids) for nm, ids in ids_of.items()}
     nums = check.numbers(cfg, cfg["lr"], pts, p, r, tbl)
     ok, rows_cmp = check.verdict(nums, cfg["limits"])
     log(f"reference: {C} steps over {u_all.size} rows in {ref_s:.1f} s; rows "

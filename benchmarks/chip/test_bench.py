@@ -157,16 +157,14 @@ TINY = dict(num_tables=4, rows_per_table=20_000, embed_dim=16,
 TINY_MIX = {"locality": "medium", "warmup_steps": 12}
 
 
-@pytest.fixture()
-def tiny_root(tmp_path):
-    """A checkout with one tiny cell of the l20 configuration, for the CPU."""
+def _tiny_checkout(tmp_path, cfg):
+    """A checkout whose one cell, ``tiny``, runs ``cfg`` on the CPU."""
     import jax
 
     for d in ("configs", "traffic"):
         (tmp_path / d).mkdir()
     (tmp_path / "src").symlink_to(ROOT / "src")
     shutil.copytree(HERE / "metrics", tmp_path / "metrics")
-    cfg = dict(_l20(), **TINY)
     (tmp_path / "configs" / "tiny.json").write_text(json.dumps(cfg))
     (tmp_path / "traffic" / "tiny.json").write_text(json.dumps(TINY_MIX))
     peaks = json.loads((HERE / "peaks.json").read_text())
@@ -179,6 +177,12 @@ def tiny_root(tmp_path):
                                config="tiny", traffic="tiny")]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp_path
+
+
+@pytest.fixture()
+def tiny_root(tmp_path):
+    """A checkout with one tiny cell of the l20 configuration, for the CPU."""
+    return _tiny_checkout(tmp_path, dict(_l20(), **TINY))
 
 
 def _run_tiny(root, capsys, seed=11):
@@ -287,10 +291,12 @@ def test_planted_fault_is_not_correct(fault, tiny_root, capsys, monkeypatch):
     assert res["correct"] is False, res["checks"]
 
 
-def test_control_is_not_correct():
+@pytest.mark.parametrize("tables", ["equal", "unequal"])
+def test_control_is_not_correct(tables):
     import control
 
-    cfg = dict(_l20(), **TINY)
+    cfg = (dict(_l20(), **TINY) if tables == "equal"
+           else dict(_unequal_cfg(), num_tables=4))
     nums, _ = control.readings(cfg, TINY_MIX, seed=7)
     for variant, n in nums.items():
         ok, _ = check.verdict(n, {k: v for k, v in cfg["limits"].items() if k in n})
@@ -302,3 +308,255 @@ def test_no_tpu_no_result(capsys):
                    "--seconds", "1", "--trace", "0"])
     assert rc != 0
     assert capsys.readouterr().out.strip() == ""
+
+
+# ---------------------------------------------------------------------- #
+# tables of unequal rows and pooling
+# ---------------------------------------------------------------------- #
+UNEQUAL_ROWS = [3, 64, 5_000, 200_000]
+
+
+def test_generator_per_table_rows_matches_program():
+    """With ``table_rows`` and one pooling, each table draws from its own
+    Zipf, table by table, as the program's ``dlrm_batches_group`` does."""
+    from repro.core.table_group import TableGroup, TableSpec
+    from repro.data.synthetic import dlrm_batches_group
+
+    cfg = {"table_rows": UNEQUAL_ROWS, "num_tables": 4, "lookups_per_table": 5,
+           "batch_size": 16, "num_dense_features": 13}
+    kw = traffic_gen.stream_kwargs(cfg, {"locality": "medium"}, 4321)
+    assert kw["table_rows"] == UNEQUAL_ROWS and kw["pooling"] == [5] * 4
+    group = TableGroup([TableSpec(f"t{i}", r, 8)
+                        for i, r in enumerate(UNEQUAL_ROWS)])
+    theirs = dlrm_batches_group(group, 4, batch_size=16, lookups_per_table=5,
+                                locality="medium", seed=4321)
+    for (ids_a, pa), (ids_b, pb) in zip(traffic_gen.dlrm_batches(**kw), theirs):
+        assert ids_a.shape == (16, 4, 5)
+        np.testing.assert_array_equal(ids_a, ids_b)
+        np.testing.assert_array_equal(pa["dense"], pb["dense"])
+        np.testing.assert_array_equal(pa["label"], pb["label"])
+
+
+def test_generator_unequal_pooling():
+    pool = [1, 3, 1, 7]
+    cfg = {"table_rows": UNEQUAL_ROWS, "pooling": pool, "num_tables": 4,
+           "batch_size": 64, "num_dense_features": 13}
+    gen = traffic_gen.dlrm_batches(
+        **traffic_gen.stream_kwargs(cfg, {"locality": "high"}, 2**33 + 1))
+    offsets = traffic_gen.row_offsets(cfg)
+    cols = np.repeat(np.arange(4), pool)  # the table of each column
+    for _ in range(3):
+        ids, pl = next(gen)
+        assert ids.shape == (64, sum(pool)) and ids.dtype == np.int64
+        assert pl["dense"].shape == (64, 13) and pl["label"].shape == (64,)
+        assert (ids >= offsets[cols]).all() and (ids < offsets[cols + 1]).all()
+        np.testing.assert_array_equal(traffic_gen.table_of(offsets, ids),
+                                      np.broadcast_to(cols, ids.shape))
+    assert np.unique(ids[:, 0]).size <= 3  # the 3-row table
+
+
+def test_generator_without_new_keys_is_unchanged():
+    """The l20 stream at a fixed seed: a digest of its first three batches,
+    recorded before per-table rows and pooling were added."""
+    import hashlib
+
+    mix = json.loads((HERE / "traffic" / "l20-medium.json").read_text())
+    kw = traffic_gen.stream_kwargs(_l20(), mix, 2**33 + 7)
+    assert "table_rows" not in kw and "pooling" not in kw
+    gen = traffic_gen.dlrm_batches(**kw)
+    h = hashlib.sha256()
+    for _ in range(3):
+        ids, pl = next(gen)
+        assert ids.shape == (2048, 8, 20)
+        for a in (ids, pl["dense"], pl["label"]):
+            h.update(a.tobytes())
+    assert h.hexdigest() == (
+        "c6dd3976c9a5ef7054f5782f5ed28c03aced327f1ee30e97a4d7c0b9609830ef")
+    offsets = traffic_gen.row_offsets(_l20())
+    np.testing.assert_array_equal(traffic_gen.table_of(offsets, ids),
+                                  ids // 4_000_000)
+
+
+def _dlrm_states():
+    """(program, reference) states of a small DLRM tree, 3 tables x 50
+    rows, for ``check.numbers`` at a warm-up of 8 steps."""
+    rng = np.random.default_rng(5)
+
+    def mlp(dims):
+        return [{"w": rng.standard_normal((a, b)).astype(np.float32),
+                 "b": rng.standard_normal(b).astype(np.float32)}
+                for a, b in zip(dims[:-1], dims[1:])]
+
+    def jitter(tree, k):
+        return {p: [{n: (lyr[n] + k * rng.standard_normal(lyr[n].shape))
+                     .astype(np.float32) for n in ("w", "b")}
+                    for lyr in tree[p]] for p in tree}
+
+    base = {"bottom": mlp([5, 8, 4]), "top": mlp([10, 6, 1])}
+    ids_of = {"first": np.arange(0, 150, 3), "step1": np.arange(0, 150, 5),
+              "steady1": np.arange(1, 150, 4), "steady3": np.arange(2, 150, 7),
+              "returning": np.arange(0, 150, 11),
+              "writeback": np.arange(3, 150, 13)}
+    states = (0, 1, 3, 8, 9, 11)
+
+    def side(k):
+        return {"loss": {i: 0.7 + 0.01 * i + k * rng.standard_normal()
+                         for i in range(1, 12)},
+                "mlps": {n: jitter(base, 0.01 * (n + 1) if n else 0.0)
+                         for n in states},
+                "rows": {}}
+
+    p, r = side(1e-3), side(0.0)
+    for n in states:
+        r["mlps"][n] = jitter(p["mlps"][n], 1e-3)
+    for n, names in [(0, ids_of), (1, ["step1"]), (3, ["first"]),
+                     (8, ["steady1", "returning"]), (9, ["steady1"]),
+                     (11, ["steady3"])]:
+        for nm in names:
+            x = rng.standard_normal((ids_of[nm].size, 4)).astype(np.float32)
+            p["rows"][(n, nm)] = x
+            r["rows"][(n, nm)] = (x + 1e-3 * rng.standard_normal(x.shape)
+                                  ).astype(np.float32) if n else x
+    for s in (p, r):
+        s["rows"][("end", "writeback")] = rng.standard_normal(
+            (ids_of["writeback"].size, 4)).astype(np.float32)
+    return p, r, {k: v // 50 for k, v in ids_of.items()}
+
+
+def test_check_numbers_on_dlrm_tree_unchanged():
+    """``check.numbers`` on a DLRM tree reads what it read before the dense
+    leaves were taken from any tree (values recorded then)."""
+    p, r, tbl = _dlrm_states()
+    got = check.numbers({"num_tables": 3}, 0.01, check.points(8), p, r, tbl)
+    assert got == {
+        "loss_gap": 0.0012521562479898773,
+        "grad_gap": 0.01975329382715514,
+        "grad_where": "step1@1 bottom1.w",
+        "grad_at": {"step1@1": 0.01975329382715514,
+                    "steady1@9": 0.0028270774146114293},
+        "change_gap": 0.009054533560260942,
+        "change_where": "first@3 top0.b",
+        "change_at": {"first@3": 0.009054533560260942,
+                      "returning@8": 0.00023723899092500237,
+                      "steady1@9": 0.004600195482153308,
+                      "steady3@11": 0.004027161804920857},
+        "writeback_gap": 0.05993570256716395,
+        "writeback_where": "table1",
+    }
+    names = [n for n, _ in check.leaves(p["mlps"][1], p["rows"][(1, "step1")],
+                                        tbl["step1"], 3)]
+    assert names == ["bottom0.b", "bottom0.w", "bottom1.b", "bottom1.w",
+                     "top0.b", "top0.w", "top1.b", "top1.w",
+                     "table0", "table1", "table2"]
+
+
+def test_table_with_no_rows_has_no_leaf():
+    rows = np.ones((3, 2), np.float32)
+    got = check.leaves({}, rows, np.array([0, 2, 2]), 4)
+    assert [n for n, _ in got] == ["table0", "table2"]
+    gap, where, n = check.worst_leaf_gap([], [])
+    assert np.isnan(gap) and n == 0
+    nums = {"writeback_gap": gap}
+    assert not check.verdict(nums, {"writeback_gap": 0.05})[0]
+
+
+def test_check_compares_every_dense_leaf():
+    """A part of the tree beyond the MLPs (a cross network) is compared: a
+    step that leaves it unchanged fails."""
+    p, r, tbl = _dlrm_states()
+    rng = np.random.default_rng(6)
+    for n in p["mlps"]:
+        u = rng.standard_normal((12, 4)).astype(np.float32) * (1 + 0.01 * n)
+        p["mlps"][n]["cross"] = [{"u": u, "v": u.T.copy()}]
+        r["mlps"][n]["cross"] = [{"u": u * np.float32(1 + 1e-4),
+                                  "v": u.T * np.float32(1 + 1e-4)}]
+    limits = {k: _l20()["limits"][k] for k in ("grad_gap", "change_gap")}
+    pts = check.points(8)
+    nums = check.numbers({"num_tables": 3}, 0.01, pts, p, r, tbl)
+    assert check.verdict(nums, limits)[0], nums
+    p["mlps"][9]["cross"] = p["mlps"][8]["cross"]  # step 9 left it unchanged
+    nums = check.numbers({"num_tables": 3}, 0.01, pts, p, r, tbl)
+    assert nums["grad_where"].startswith("steady1@9 cross0."), nums
+    assert nums["grad_gap"] == pytest.approx(1.0)
+    assert not check.verdict(nums, limits)[0]
+
+
+def _unequal_cfg():
+    cfg = dict(_l20(), **TINY, table_rows=UNEQUAL_ROWS)
+    del cfg["num_tables"], cfg["rows_per_table"]  # load_cell fills num_tables
+    return cfg
+
+
+@pytest.mark.parametrize("fault", [None, "half_batch"])
+def test_unequal_tables_cell(fault, tmp_path, capsys, monkeypatch):
+    """A cell of unequal tables runs end to end on the CPU through the
+    unchanged ``run.py``: correct when sound, not with half of each batch
+    left out."""
+    from repro.core import dlrm_runtime
+
+    root = _tiny_checkout(tmp_path, _unequal_cfg())
+    _w, cfg, _mix, _e2e, _pl = run.load_cell("tiny", root, root)
+    assert cfg["num_tables"] == 4
+    if fault == "half_batch":
+        monkeypatch.setattr(dlrm_runtime, "dlrm_train_step", _half_batch_step())
+    res = _run_tiny(root, capsys, seed=2**32 + 99)
+    assert res["correct"] is (fault is None), res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+
+
+def test_table_count_must_agree(tmp_path):
+    cfg = dict(_unequal_cfg(), num_tables=5)
+    root = _tiny_checkout(tmp_path, cfg)
+    with pytest.raises(SystemExit, match="table_rows lists 4 tables"):
+        run.load_cell("tiny", root, root)
+
+
+def test_unequal_pooling_is_refused():
+    import driver_dlrm
+
+    cfg = dict(_unequal_cfg(), name="tiny", num_tables=4, pooling=[1, 4, 4, 2])
+    data = np.zeros((sum(UNEQUAL_ROWS), 16), np.float32)
+    with pytest.raises(NotImplementedError, match="Reach 2"):
+        driver_dlrm.build(cfg, None, data)
+
+
+def test_lookups_count_per_table_pooling():
+    cfg = dict(_l20(), pooling=[20] * 8)
+    assert ref.train_step_cost(cfg, 1000, 200) == ref.train_step_cost(
+        _l20(), 1000, 200)
+    flops, _ = ref.train_step_cost(dict(_l20(), pooling=[1, 2, 3, 4, 5, 6, 7, 8]),
+                                   0, 0)
+    assert flops == ref.model_flops_per_example(_l20()) * 2048 + 2 * 2048 * 36 * 128
+
+
+def test_pad_sizes_cover_every_count():
+    import driver_dlrm
+    from repro.core.plan import pad_len
+
+    class Pipe:
+        pad_buckets = None
+
+    assert driver_dlrm.pad_sizes(Pipe, 16_384) == [256 << k for k in range(7)]
+    sizes = driver_dlrm.pad_sizes(Pipe, 2048 * 8 * 20)
+    assert sizes[-1] == 524_288
+    assert {pad_len(n) for n in range(1, 2048 * 8 * 20 + 1, 97)} <= set(sizes)
+
+
+def test_no_read_or_fill_compiles_after_build():
+    """Every victim read and fill a run makes has the shape that ``build``
+    warmed up, whichever seed draws its ids."""
+    import driver_dlrm
+    import jax
+    from repro.core import scratchpad as sp
+
+    cfg = dict(_l20(), **TINY, name="tiny")
+    rows = cfg["num_tables"] * cfg["rows_per_table"]
+    data = np.zeros((rows, cfg["embed_dim"]), np.float32)
+    for seed in (3, 2**33 + 1):
+        _host, _trainer, pipe = driver_dlrm.build(cfg, jax.random.key(0), data)
+        n_read, n_fill = sp.read._cache_size(), sp.fill._cache_size()
+        gen = traffic_gen.dlrm_batches(**traffic_gen.stream_kwargs(
+            cfg, TINY_MIX, seed))
+        driver_dlrm.run(pipe, (next(gen) for _ in range(40)))
+        assert max(s.n_evict for s in pipe.stats) > 0
+        assert (sp.read._cache_size(), sp.fill._cache_size()) == (n_read, n_fill)

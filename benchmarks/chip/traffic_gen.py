@@ -2,10 +2,15 @@
 producer process that runs it ahead of the trainer.
 
 Copied from ``repro.data.synthetic`` (``zipf_ranks``, ``_coprime_scatter``,
-``dlrm_batches``) so that no later change to the program can move the
-yardstick. Ranks come from a Zipf(s) inverse CDF and are scattered over the
-id space by a bijective multiplicative hash, so hot rows are not contiguous.
-``self_test.py`` checks that this copy yields the program's ids for a seed.
+``dlrm_batches``, ``dlrm_batches_group``) so that no later change to the
+program can move the yardstick. Ranks come from a Zipf(s) inverse CDF and
+are scattered over the id space by a bijective multiplicative hash, so hot
+rows are not contiguous. ``test_bench.py`` checks that this copy yields the
+program's ids for a seed.
+
+A configuration gives equal tables (``num_tables`` x ``rows_per_table``, a
+scalar ``lookups_per_table``) or, with the optional keys ``table_rows`` and
+``pooling``, the rows and the lookups of each table.
 
 This module imports numpy only: the producer is started with ``spawn`` and
 never touches JAX, so it cannot hold the chip.
@@ -49,42 +54,95 @@ def sample_ids(rng, n_rows: int, size, s: float) -> np.ndarray:
     return ranks if s <= 0.0 else coprime_scatter(ranks, n_rows)
 
 
+def table_rows(cfg: dict) -> list:
+    """Rows of each table."""
+    if "table_rows" in cfg:
+        return [int(r) for r in cfg["table_rows"]]
+    return [int(cfg["rows_per_table"])] * int(cfg["num_tables"])
+
+
+def pooling(cfg: dict) -> list:
+    """Lookups per sample of each table."""
+    if "pooling" in cfg:
+        return [int(n) for n in cfg["pooling"]]
+    return [int(cfg["lookups_per_table"])] * int(cfg["num_tables"])
+
+
+def row_offsets(cfg: dict) -> np.ndarray:
+    """(T + 1,) int64: the first global id of each table, then the total."""
+    return np.concatenate([[0], np.cumsum(table_rows(cfg))]).astype(np.int64)
+
+
+def table_of(offsets: np.ndarray, ids: np.ndarray) -> np.ndarray:
+    """The table of each global id, for ``offsets`` from :func:`row_offsets`
+    (``ids // rows_per_table`` where the tables are equal)."""
+    return np.searchsorted(offsets, ids, side="right") - 1
+
+
+def _label(rng, dense):
+    # CTR label correlated with the dense features (a learnable signal)
+    logits = dense[:, 0] - 0.5 * dense[:, 1]
+    return (
+        rng.random(dense.shape[0]) < 1.0 / (1.0 + np.exp(-logits))
+    ).astype(np.float32)
+
+
 def dlrm_batches(*, seed, num_tables, rows_per_table, lookups_per_table,
-                 batch_size, s, num_dense_features):
-    """Endless stream of (global ids (B, T, L) int64, {"dense", "label"}),
-    one numpy Generator drawn in step order, as ``repro.data.synthetic.
-    dlrm_batches`` draws it. Global id = table * rows_per_table + local id."""
+                 batch_size, s, num_dense_features, table_rows=None,
+                 pooling=None):
+    """Endless stream of (global ids int64, {"dense", "label"}), one numpy
+    Generator drawn in step order.
+
+    Without ``table_rows`` and ``pooling``, ids are (B, T, L) drawn as one
+    block, as ``repro.data.synthetic.dlrm_batches`` draws them; global id =
+    table * rows_per_table + local id. With them, each table draws its own
+    (B, L_t) ids from its own Zipf over its own rows, table by table, as
+    ``repro.data.synthetic.dlrm_batches_group`` draws them; global id =
+    the table's first row + local id. Ids are (B, T, L) where every table
+    has the same pooling, else (B, sum of L_t) with each table's columns
+    together, in table order. The dense features and labels follow."""
     rng = np.random.default_rng(seed)
-    offs = (np.arange(num_tables, dtype=np.int64) * rows_per_table)[
-        None, :, None
-    ]
+    if table_rows is None and pooling is None:
+        offs = (np.arange(num_tables, dtype=np.int64) * rows_per_table)[
+            None, :, None
+        ]
+
+        def draw():
+            return sample_ids(
+                rng, rows_per_table,
+                (batch_size, num_tables, lookups_per_table), s,
+            ) + offs
+    else:
+        rows = table_rows or [rows_per_table] * num_tables
+        pool = pooling or [lookups_per_table] * num_tables
+        starts = np.concatenate([[0], np.cumsum(rows)[:-1]]).astype(np.int64)
+        join = np.stack if len(set(pool)) == 1 else np.concatenate
+
+        def draw():
+            return join([sample_ids(rng, r, (batch_size, n), s) + o
+                         for r, n, o in zip(rows, pool, starts)], axis=1)
     while True:
-        ids = sample_ids(
-            rng, rows_per_table,
-            (batch_size, num_tables, lookups_per_table), s,
-        )
+        ids = draw()
         dense = rng.standard_normal(
             (batch_size, num_dense_features)
         ).astype(np.float32)
-        # CTR label correlated with the dense features (a learnable signal)
-        logits = dense[:, 0] - 0.5 * dense[:, 1]
-        label = (
-            rng.random(batch_size) < 1.0 / (1.0 + np.exp(-logits))
-        ).astype(np.float32)
-        yield ids + offs, {"dense": dense, "label": label}
+        yield ids, {"dense": dense, "label": _label(rng, dense)}
 
 
 def stream_kwargs(cfg: dict, mix: dict, seed: int) -> dict:
     """Generator arguments for one (configuration, traffic mix, seed)."""
-    return dict(
+    kw = dict(
         seed=seed,
         num_tables=cfg["num_tables"],
-        rows_per_table=cfg["rows_per_table"],
-        lookups_per_table=cfg["lookups_per_table"],
+        rows_per_table=cfg.get("rows_per_table"),
+        lookups_per_table=cfg.get("lookups_per_table"),
         batch_size=cfg["batch_size"],
         s=LOCALITY_S[mix["locality"]],
         num_dense_features=cfg["num_dense_features"],
     )
+    if "table_rows" in cfg or "pooling" in cfg:
+        kw.update(table_rows=table_rows(cfg), pooling=pooling(cfg))
+    return kw
 
 
 def _produce(kwargs, q, stop):
